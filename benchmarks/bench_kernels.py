@@ -54,10 +54,10 @@ def _paged_case(B, depth, bs, seed=0):
     nb = -(-depth // bs)
     N = 1 + B * nb
     q = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((N, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((N, bs, KV, hd)), jnp.float32)
-    mp = jnp.asarray(rng.random((N, bs, KV)) < 0.9).at[0].set(False)
-    pos = jnp.asarray(rng.integers(0, depth, (N, bs, KV)), jnp.int32)
+    kp = jnp.asarray(rng.standard_normal((N, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((N, KV, bs, hd)), jnp.float32)
+    mp = jnp.asarray(rng.random((N, KV, bs)) < 0.9).at[0].set(False)
+    pos = jnp.asarray(rng.integers(0, depth, (N, KV, bs)), jnp.int32)
     tbl = jnp.asarray(1 + np.arange(B * nb, dtype=np.int32).reshape(B, nb))
     return q, kp, vp, mp, pos, tbl
 

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.bench_accuracy import _kept_sets, _needle_survival, _overlap
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import EvictionConfig
 from repro.configs import get_smoke_config
 from repro.core import objective, policies
@@ -273,6 +274,7 @@ def run(report):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     def report(name, us, derived=""):
         print(f"{name},{'' if us is None else f'{us:.1f}'},{derived}")
 

@@ -42,6 +42,7 @@ import numpy as np
 from benchmarks.bench_serving import (BUDGET, CHUNK, MAX_NEW, PROMPT_LENS,
                                       make_trace)
 from benchmarks.common import clone_requests
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import EvictionConfig
 from repro.configs import get_smoke_config
 from repro.core import objective
@@ -212,6 +213,7 @@ def main():
     ap.add_argument("--trials", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     res, drift, eng_on, trace = bench(n_requests=args.requests,
                                       trials=args.trials, seed=args.seed)
     eng_on.metrics.to_json(METRICS_OUT)

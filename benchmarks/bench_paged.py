@@ -44,6 +44,7 @@ import numpy as np
 
 from benchmarks.common import (decode_step_stats, engine_stats,
                                make_poisson_trace, ttft_stats)
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import EvictionConfig
 from repro.configs import get_smoke_config
 from repro.core.lookahead import init_lookahead_params
@@ -169,6 +170,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trials", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
     res = bench(args.seed, args.trials)
     print(f"{'engine':8s} {'conc':>5s} {'ttft_p95':>9s} {'ttft_mean':>10s} "
           f"{'kv_bytes':>9s} {'preempt':>8s} {'step_ms':>8s} "

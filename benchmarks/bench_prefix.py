@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import clone_requests, engine_stats, ttft_stats
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import EvictionConfig
 from repro.configs import get_smoke_config
 from repro.data.synthetic import make_prefix_trace
@@ -171,6 +172,7 @@ def main():
     ap.add_argument("--requests", type=int, default=14)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     res = bench(args.requests, args.seed)
     print(f"{'engine':8s} {'ttft_ms':>9s} {'ttft_p95':>9s}")
     for name in ("off", "on"):

@@ -11,6 +11,8 @@ import glob
 import json
 import os
 
+from repro.common.compile_cache import enable_compile_cache
+
 DRYRUN_DIR = os.environ.get("REPRO_DRYRUN_DIR", "experiments/dryrun")
 
 
@@ -125,6 +127,7 @@ def run(report):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print(markdown_table(load_results()))
     print()
     print("## Paged flash-decode budget (measured)")

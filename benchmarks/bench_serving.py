@@ -47,6 +47,7 @@ import numpy as np
 
 from benchmarks.common import (clone_requests, decode_step_stats,
                                engine_stats, make_poisson_trace, ttft_stats)
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import EvictionConfig
 from repro.configs import get_smoke_config
 from repro.core.lookahead import init_lookahead_params
@@ -358,6 +359,7 @@ def main():
                     help="also run the paged-pool decode-eviction "
                          "comparison (concurrency at equal KV bytes)")
     args = ap.parse_args()
+    enable_compile_cache()
     res = bench(args.requests, args.rate, args.policy, args.slots,
                 args.seed, warmup=not args.no_warmup,
                 long_tail=args.long_tail, long_len=args.long_len,

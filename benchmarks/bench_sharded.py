@@ -41,6 +41,8 @@ import os
 import subprocess
 import sys
 
+from repro.common.compile_cache import enable_compile_cache
+
 MODEL_WIDTHS = (1, 2, 4)
 N_DEVICES = 8
 CHUNK = 32
@@ -111,8 +113,13 @@ def _child_bench() -> dict:
 
 
 def bench() -> dict:
-    """Spawn the forced-multi-device child and collect its measurements."""
+    """Spawn the forced-multi-device child and collect its measurements.
+
+    The child measures a forced *host* mesh, so it is pinned to the CPU
+    backend: a parent that already holds an accelerator (ci_smoke's earlier
+    suites) would otherwise leave the child waiting on the device."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     flags = env.get("XLA_FLAGS", "")
     env["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={N_DEVICES}"
@@ -178,6 +185,7 @@ def main():
     ap.add_argument("--child", action="store_true",
                     help="internal: run the measurement in-process")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.child:
         print(_MARK + json.dumps(_child_bench()), flush=True)
         return

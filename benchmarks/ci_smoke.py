@@ -55,6 +55,8 @@ import json
 import sys
 import time
 
+from repro.common.compile_cache import enable_compile_cache
+
 # every row name ending in ``_verdict`` gates the job
 SUITES = ("benchmarks.bench_kernels", "benchmarks.bench_serving",
           "benchmarks.bench_prefix", "benchmarks.bench_paged",
@@ -66,6 +68,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_ci.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rows: list[dict] = []
     verdicts: dict[str, str] = {}

@@ -12,6 +12,8 @@ import argparse
 import sys
 import time
 
+from repro.common.compile_cache import enable_compile_cache
+
 SUITES = {
     "accuracy": "benchmarks.bench_accuracy",        # Fig 4 / Tables 9-14
     "ttft": "benchmarks.bench_ttft",                # Table 3/15, Fig 3
@@ -30,6 +32,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma-separated suite names (default: all)")
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(SUITES)
 
     print("name,us_per_call,derived")

@@ -186,8 +186,8 @@ def cache_specs(cfg: ModelConfig, mesh, batch: int, capacity: int,
 def pool_specs(cfg: ModelConfig, mesh) -> Optional[dict]:
     """Sharding for the paged KV block pool (``serving/kv_pool.py``).
 
-    The pool arrays are ``(L, num_blocks, block_size, KV, hd)`` K/V plus
-    ``(L, num_blocks, block_size, KV)`` pos/mask; only the kv-head dim is
+    The pool arrays are ``(L, num_blocks, KV, block_size, hd)`` K/V plus
+    ``(L, num_blocks, KV, block_size)`` pos/mask; only the kv-head dim is
     sharded, on "model" — blocks are *whole* on every shard, so the host
     free-list allocator and the per-request block tables stay replicated
     and allocation logic is untouched.  Returns None when the mesh has no
@@ -201,11 +201,25 @@ def pool_specs(cfg: ModelConfig, mesh) -> Optional[dict]:
     if not _div(a.num_kv_heads, mesh.shape["model"]):
         return None
     return {
-        "k": P(None, None, None, "model", None),
-        "v": P(None, None, None, "model", None),
-        "pos": P(None, None, None, "model"),
-        "mask": P(None, None, None, "model"),
+        "k": P(None, None, "model", None, None),
+        "v": P(None, None, "model", None, None),
+        "pos": P(None, None, "model", None),
+        "mask": P(None, None, "model", None),
     }
+
+
+def init_params_sharded(key, cfg: ModelConfig, mesh) -> Any:
+    """``transformer.init_params`` created already sharded by
+    ``param_specs``: one jitted init whose outputs are laid out per shard,
+    so no device ever holds the whole model (a model larger than one
+    chip's memory can only be built this way)."""
+    from repro.models import transformer as tf
+
+    shardings = jax.tree.map(lambda sp: NamedSharding(mesh, sp),
+                             param_specs(cfg, mesh),
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(lambda k: tf.init_params(k, cfg),
+                   out_shardings=shardings)(key)
 
 
 def mesh_signature(mesh) -> Optional[tuple]:

@@ -12,7 +12,11 @@ Tiling: grid = (B, H, nk) with the key axis innermost (sequential).  The
 whole chunk rides in VMEM as a single (C, hd) query tile; key blocks whose
 first column lies beyond the chunk's last visible position are skipped
 (the usual causal block pruning — for an early chunk of a long buffer
-almost every key block short-circuits).
+almost every key block short-circuits).  The kernels take head-major
+operands — q (B, H, C, hd), k/v (B, KV, K, hd) — so every tile's two
+minor dimensions are (rows, hd), the layout Mosaic tiles (8·k, 128·k);
+``ops.chunk_attention`` transposes the sequence-major model buffers and
+pads the key axis to lane-aligned tiles.
 
 GQA is handled in the index map (query head ``h`` reads kv head
 ``h // group``).  Oracle: ``ref.attention`` with explicit ``q_pos``.
@@ -43,7 +47,39 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import head_major, key_tiling
+
 NEG_INF = -1e30
+
+
+def _tile(q_ref, k_ref, s0, ik, *, window, block_k, C, scale):
+    """(s, ok): scaled logits of the chunk against key tile ``ik`` and
+    their causal / windowed visibility."""
+    q = q_ref[0, 0].astype(jnp.float32)  # (C, hd)
+    k = k_ref[0, 0].astype(jnp.float32)  # (bk, hd)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # (C, bk)
+    q_pos = s0 + jax.lax.broadcasted_iota(jnp.int32, (C, block_k), 0)
+    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (C, block_k), 1)
+    ok = k_pos <= q_pos
+    if window is not None:
+        ok &= (q_pos - k_pos) < window
+    return jnp.where(ok, s, NEG_INF), ok
+
+
+def _online_update(s, ok, v_ref, m_scr, l_scr, acc_scr):
+    """One key tile of the online-softmax recurrence; (C, 1) row stats."""
+    v = v_ref[0, 0].astype(jnp.float32)
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    m_scr[...] = m_new
 
 
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -61,35 +97,19 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     # blocks starting past it contain no visible column for any row.
     @pl.when(ik * block_k <= s0 + C - 1)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (C, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (C, bk)
-
-        q_pos = s0 + jax.lax.broadcasted_iota(jnp.int32, (C, block_k), 0)
-        k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (C, block_k), 1)
-        ok = k_pos <= q_pos
-        if window is not None:
-            ok &= (q_pos - k_pos) < window
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
+        s, ok = _tile(q_ref, k_ref, s0, ik, window=window, block_k=block_k,
+                      C=C, scale=scale)
+        _online_update(s, ok, v_ref, m_scr, l_scr, acc_scr)
 
     @pl.when(ik == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _scratch(C: int, hd: int) -> list:
+    return [pltpu.VMEM((C, 1), jnp.float32), pltpu.VMEM((C, 1), jnp.float32),
+            pltpu.VMEM((C, hd), jnp.float32)]
 
 
 def chunk_attention_pallas(
@@ -105,10 +125,8 @@ def chunk_attention_pallas(
     B, C, H, hd = q.shape
     K, KV = k.shape[1], k.shape[2]
     group = H // KV
-    block_k = min(block_k, K)
-    while K % block_k:
-        block_k //= 2
-    nk = K // block_k
+    block_k, Kp = key_tiling(K, block_k)
+    nk = Kp // block_k
     scale = 1.0 / (hd ** 0.5)
     if window == 0:
         window = None
@@ -121,26 +139,23 @@ def chunk_attention_pallas(
         num_scalar_prefetch=1,
         grid=(B, H, nk),
         in_specs=[
-            pl.BlockSpec((1, C, 1, hd), lambda b, h, ik, offs: (b, 0, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, ik, offs, g=group: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, ik, offs, g=group: (b, ik, h // g, 0)),
+            pl.BlockSpec((1, 1, C, hd), lambda b, h, ik, offs: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, ik, offs, g=group: (b, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, ik, offs, g=group: (b, h // g, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, C, 1, hd),
-                               lambda b, h, ik, offs: (b, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((C,), jnp.float32),
-            pltpu.VMEM((C,), jnp.float32),
-            pltpu.VMEM((C, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, 1, C, hd),
+                               lambda b, h, ik, offs: (b, h, 0, 0)),
+        scratch_shapes=_scratch(C, hd),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, C, hd), q.dtype),
         interpret=interpret,
-    )(offs, q, k, v)
+    )(offs, head_major(q), head_major(k, Kp), head_major(v, Kp))
+    return jnp.swapaxes(out, 1, 2)
 
 
 def _fused_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, mass_ref,
@@ -160,57 +175,36 @@ def _fused_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, mass_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _tile():
-        """(s, ok) logits + visibility of this key tile — shared by phases."""
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (C, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, hd)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (C, bk)
-        q_pos = s0 + jax.lax.broadcasted_iota(jnp.int32, (C, block_k), 0)
-        k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (C, block_k), 1)
-        ok = k_pos <= q_pos
-        if window is not None:
-            ok &= (q_pos - k_pos) < window
-        return jnp.where(ok, s, NEG_INF), ok
+    def tile():
+        return _tile(q_ref, k_ref, s0, ik, window=window, block_k=block_k,
+                     C=C, scale=scale)
 
     # phase 0: the unmodified online-softmax attention recurrence — the
     # attention output is bit-identical to the single-pass kernel's.
     @pl.when(jnp.logical_not(phase1) & live)
     def _pass1():
-        s, ok = _tile()
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
+        s, ok = tile()
+        _online_update(s, ok, v_ref, m_scr, l_scr, acc_scr)
 
     @pl.when(j == nk - 1)
     def _finish_o():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
     # phase 1: the (m, l) statistics are final — re-stream the key tile and
     # emit its normalized column masses, zeroing invalid (padded) rows.
     @pl.when(phase1 & live)
     def _pass2():
-        s, ok = _tile()
-        m = m_scr[...]
+        s, ok = tile()
         l = jnp.maximum(l_scr[...], 1e-30)
-        p = jnp.where(ok, jnp.exp(s - m[:, None]), 0.0) / l[:, None]
+        p = jnp.where(ok, jnp.exp(s - m_scr[...]), 0.0) / l
         row = s0 + jax.lax.broadcasted_iota(jnp.int32, (C, block_k), 0)
         p = jnp.where(row < n_total, p, 0.0)
-        mass_ref[0, 0, :] = p.sum(axis=0)
+        mass_ref[0, 0] = p.sum(axis=0, keepdims=True)
 
     @pl.when(phase1 & jnp.logical_not(live))
     def _pass2_pruned():  # causally invisible tile: exact zero mass
-        mass_ref[0, 0, :] = jnp.zeros((block_k,), jnp.float32)
+        mass_ref[0, 0] = jnp.zeros((1, block_k), jnp.float32)
 
 
 def chunk_attention_masses_pallas(
@@ -235,10 +229,8 @@ def chunk_attention_masses_pallas(
     B, C, H, hd = q.shape
     K, KV = k.shape[1], k.shape[2]
     group = H // KV
-    block_k = min(block_k, K)
-    while K % block_k:
-        block_k //= 2
-    nk = K // block_k
+    block_k, Kp = key_tiling(K, block_k)
+    nk = Kp // block_k
     scale = 1.0 / (hd ** 0.5)
     if window == 0:
         window = None
@@ -253,45 +245,43 @@ def chunk_attention_masses_pallas(
         num_scalar_prefetch=1,
         grid=(B, H, 2 * nk),
         in_specs=[
-            pl.BlockSpec((1, C, 1, hd), lambda b, h, j, offs: (b, 0, h, 0)),
+            pl.BlockSpec((1, 1, C, hd), lambda b, h, j, offs: (b, h, 0, 0)),
             pl.BlockSpec(
-                (1, block_k, 1, hd),
+                (1, 1, block_k, hd),
                 lambda b, h, j, offs, g=group, nk=nk: (
-                    b, jnp.where(j < nk, j, j - nk), h // g, 0
+                    b, h // g, jnp.where(j < nk, j, j - nk), 0
                 ),
             ),
             # v is only read in phase 0; phase-1 iterations park on block 0
             # so the mass sweep doesn't re-stream the whole v buffer
             pl.BlockSpec(
-                (1, block_k, 1, hd),
+                (1, 1, block_k, hd),
                 lambda b, h, j, offs, g=group, nk=nk: (
-                    b, jnp.where(j < nk, j, 0), h // g, 0
+                    b, h // g, jnp.where(j < nk, j, 0), 0
                 ),
             ),
         ],
         out_specs=[
-            pl.BlockSpec((1, C, 1, hd), lambda b, h, j, offs: (b, 0, h, 0)),
+            pl.BlockSpec((1, 1, C, hd), lambda b, h, j, offs: (b, h, 0, 0)),
             # phase-0 iterations park on mass block 0 (key block 0 is never
             # causally pruned, so phase 1's first iteration overwrites it
-            # before any write-back escapes); phase 1 emits block ik
+            # before any write-back escapes); phase 1 emits block ik.  The
+            # unit axis keeps the block's minor dims (1, block_k) tileable.
             pl.BlockSpec(
-                (1, 1, block_k),
-                lambda b, h, j, offs, nk=nk: (b, h, jnp.where(j < nk, 0,
-                                                              j - nk)),
+                (1, 1, 1, block_k),
+                lambda b, h, j, offs, nk=nk: (b, h, 0, jnp.where(j < nk, 0,
+                                                                 j - nk)),
             ),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((C,), jnp.float32),
-            pltpu.VMEM((C,), jnp.float32),
-            pltpu.VMEM((C, hd), jnp.float32),
-        ],
+        scratch_shapes=_scratch(C, hd),
     )
-    return pl.pallas_call(
+    out, masses = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, C, H, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, K), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, C, hd), q.dtype),
+            jax.ShapeDtypeStruct((B, H, 1, Kp), jnp.float32),
         ],
         interpret=interpret,
-    )(offs, q, k, v)
+    )(offs, head_major(q), head_major(k, Kp), head_major(v, Kp))
+    return jnp.swapaxes(out, 1, 2), masses[:, :, 0, :K]

@@ -3,9 +3,12 @@ KV cache.
 
 Decode is memory-bound: the roofline term is cache bytes / HBM bandwidth, so
 the kernel's job is to stream K/V tiles exactly once at full bandwidth while
-the (1 × block_k) score tile lives in registers/VMEM.  grid = (B, H, nk),
+the (G × block_k) score tile lives in registers/VMEM.  grid = (B, KV, nk),
 key axis innermost with (m, l, acc) scratch carry — the flash-attention
-recurrence specialized to a single query row.
+recurrence specialized to one query row per head, each kv head's GQA
+group of ``G = H // KV`` query rows attending one (block_k, hd) key tile.
+The wrapper hands the kernel head-major K/V (B, KV, Sk, hd), keys padded
+to lane-aligned tiles.
 
 Oracle: ``ref.decode_attention``.  jnp fallback in ``ops.decode_attention``.
 """
@@ -18,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import head_major, key_tiling
 
 NEG_INF = -1e30
 
@@ -32,25 +37,28 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0, :].astype(jnp.float32)  # (hd,)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (block_k, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    ok = mask_ref[0, :]  # (block_k,)
-    s = (k @ q) * scale  # (block_k,)
+    q = q_ref[0, 0].astype(jnp.float32)  # (G, hd)
+    k = k_ref[0, 0].astype(jnp.float32)  # (block_k, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
+    ok = mask_ref[0]  # (1, block_k)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # (G, block_k)
     s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_scr[0]
-    m_new = jnp.maximum(m_prev, s.max())
-    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)  # (block_k,)
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[0] = l_scr[0] * corr + p.sum()
-    acc_scr[...] = acc_scr[...] * corr + p @ v  # (hd,)
-    m_scr[0] = m_new
+    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finish():
-        l = jnp.maximum(l_scr[0], 1e-30)
-        o_ref[0, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(
@@ -64,36 +72,30 @@ def decode_attention_pallas(
 ) -> jnp.ndarray:
     B, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
-    group = H // KV
-    block_k = min(block_k, Sk)
-    pad = (-Sk) % block_k
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    G = H // KV
+    block_k, Skp = key_tiling(Sk, block_k)
     mask = jnp.ones((B, Sk), bool) if kv_mask is None else kv_mask
-    if pad:
-        mask = jnp.pad(mask, ((0, 0), (0, pad)))
-    nk = (Sk + pad) // block_k
+    mask = jnp.pad(mask, ((0, 0), (0, Skp - Sk)))[:, None, :]
+    nk = Skp // block_k
     scale = 1.0 / (hd ** 0.5)
 
     kernel = functools.partial(_kernel, nk=nk, scale=scale)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=(B, H, nk),
+        grid=(B, KV, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, ik: (b, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, ik, g=group: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, ik, g=group: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, ik: (b, ik)),
+            pl.BlockSpec((1, 1, G, hd), lambda b, h, ik: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, ik: (b, 0, ik)),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda b, h, ik: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, ik: (b, h, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((hd,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, mask)
+    )(q.reshape(B, KV, G, hd), head_major(k, Skp), head_major(v, Skp), mask)
+    return out.reshape(B, H, hd)

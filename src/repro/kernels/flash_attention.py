@@ -7,7 +7,9 @@ score matrix never exists.  MXU alignment: block_q/block_k default to
 128-multiples; hd is 32–256 in our configs.
 
 GQA is handled in the index map: the key/value block for query head ``h``
-reads kv head ``h // group`` — no materialized repeat.
+reads kv head ``h // group`` — no materialized repeat.  The kernel takes
+head-major operands (the wrapper transposes), so each tile's minor dims
+are (rows, hd).
 
 Oracle: ``ref.attention``.  jnp fallback with identical math:
 ``ops._chunked_attention``.
@@ -21,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import head_major
 
 NEG_INF = -1e30
 
@@ -36,9 +40,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)  # (bq, hd)
+    k = k_ref[0, 0].astype(jnp.float32)  # (bk, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # (bq, bk)
@@ -53,11 +57,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-    acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     m_scr[...] = m_new
@@ -65,7 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ik == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(
@@ -96,23 +100,24 @@ def flash_attention_pallas(
         _kernel, causal=causal, window=window,
         block_q=block_q, block_k=block_k, nk=nk, scale=scale,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
+            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, hd),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(head_major(q), head_major(k), head_major(v))
+    return jnp.swapaxes(out, 1, 2)

@@ -13,7 +13,10 @@ grid runs the key axis twice (phase trick): phase 0 accumulates the online
 ``exp(s − m)/l`` column means directly — the (n_obs × Sk) score matrix never
 hits HBM, and output traffic is Sk floats per head instead of n_obs·Sk.
 
-grid = (B, H, 2·nk); phase = ik // nk.
+grid = (B, H, 2·nk); phase = ik // nk.  Operands are head-major — q_obs
+(B, H, n_obs, hd), keys (B, KV, Sk, hd) — so every tile's minor dimensions
+are (rows, hd); the wrapper transposes and pads the key axis to
+lane-aligned tiles.
 
 This is the one masked streaming scoring primitive every observation-style
 policy rides (chunked *and* monolithic prefill):
@@ -38,6 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import head_major, key_tiling
+
 NEG_INF = -1e30
 
 
@@ -53,8 +58,8 @@ def _kernel(offs_ref, q_ref, k_ref, mask_ref, rv_ref, o_ref, m_scr, l_scr, *,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # (n_obs, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (block_k, hd)
+    q = q_ref[0, 0].astype(jnp.float32)  # (n_obs, hd)
+    k = k_ref[0, 0].astype(jnp.float32)  # (block_k, hd)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # (n_obs, block_k)
@@ -64,24 +69,25 @@ def _kernel(offs_ref, q_ref, k_ref, mask_ref, rv_ref, o_ref, m_scr, l_scr, *,
     ok = k_pos <= q_pos  # causal among obs rows; prompt keys all visible
     if window is not None:
         ok &= (q_pos - k_pos) < window
-    ok &= mask_ref[0, :][None, :]  # key validity (padding / evicted)
+    ok &= mask_ref[0]  # (1, block_k) key validity (padding / evicted)
     s = jnp.where(ok, s, NEG_INF)
 
     @pl.when(jnp.logical_not(phase1))
     def _pass1():
         m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-        l_scr[...] = l_scr[...] * jnp.exp(m_prev - m_new) + p.sum(axis=-1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        l_scr[...] = (l_scr[...] * jnp.exp(m_prev - m_new)
+                      + p.sum(axis=-1, keepdims=True))
         m_scr[...] = m_new
 
     @pl.when(phase1)
     def _pass2():
-        m = m_scr[...]
         l = jnp.maximum(l_scr[...], 1e-30)
-        p = jnp.where(ok, jnp.exp(s - m[:, None]), 0.0) / l[:, None]
-        p = p * rv_ref[0, :][:, None].astype(jnp.float32)
-        o_ref[0, 0, :] = (p.sum(axis=0) / n_obs).astype(o_ref.dtype)
+        p = jnp.where(ok, jnp.exp(s - m_scr[...]), 0.0) / l
+        p = p * rv_ref[0]  # (n_obs, 1) real-row weights
+        o_ref[0, 0] = (p.sum(axis=0, keepdims=True) / n_obs).astype(
+            o_ref.dtype)
 
 
 def lookahead_score_pallas(
@@ -103,18 +109,14 @@ def lookahead_score_pallas(
     if window == 0:
         window = None
 
-    block_k = min(block_k, Sk)
-    pad = (-Sk) % block_k
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    block_k, Skp = key_tiling(Sk, block_k)
     full_mask = jnp.ones((B, Sk), bool)
     if kv_mask is not None:
         full_mask = full_mask.at[:, :n_prompt].set(kv_mask)
-    if pad:
-        full_mask = jnp.pad(full_mask, ((0, 0), (0, pad)))
+    full_mask = jnp.pad(full_mask, ((0, 0), (0, Skp - Sk)))[:, None, :]
     if row_valid is None:
         row_valid = jnp.ones((B, n_obs), bool)
-    Skp = Sk + pad
+    rv = row_valid.astype(jnp.float32)[..., None]  # (B, n_obs, 1)
     nk = Skp // block_k
     offs = jnp.reshape(
         jnp.asarray(n_prompt if q_offset is None else q_offset, jnp.int32),
@@ -128,35 +130,37 @@ def lookahead_score_pallas(
         num_scalar_prefetch=1,
         grid=(B, H, 2 * nk),
         in_specs=[
-            pl.BlockSpec((1, n_obs, 1, hd), lambda b, h, j, offs: (b, 0, h, 0)),
+            pl.BlockSpec((1, 1, n_obs, hd), lambda b, h, j, offs: (b, h, 0, 0)),
             pl.BlockSpec(
-                (1, block_k, 1, hd),
+                (1, 1, block_k, hd),
                 lambda b, h, j, offs, g=group, nk=nk: (
-                    b, jnp.where(j < nk, j, j - nk), h // g, 0
+                    b, h // g, jnp.where(j < nk, j, j - nk), 0
                 ),
             ),
             pl.BlockSpec(
-                (1, block_k),
-                lambda b, h, j, offs, nk=nk: (b, jnp.where(j < nk, j, j - nk)),
+                (1, 1, block_k),
+                lambda b, h, j, offs, nk=nk: (
+                    b, 0, jnp.where(j < nk, j, j - nk)),
             ),
-            pl.BlockSpec((1, n_obs), lambda b, h, j, offs: (b, 0)),
+            pl.BlockSpec((1, n_obs, 1), lambda b, h, j, offs: (b, 0, 0)),
         ],
         # phase-0 iterations park on block 0 (never written by the kernel in
         # that phase; phase 1's first iteration overwrites it before any
         # write-back escapes), phase-1 iterations emit block ik.
         out_specs=pl.BlockSpec(
-            (1, 1, block_k),
-            lambda b, h, j, offs, nk=nk: (b, h, jnp.where(j < nk, 0, j - nk)),
+            (1, 1, 1, block_k),
+            lambda b, h, j, offs, nk=nk: (b, h, 0,
+                                          jnp.where(j < nk, 0, j - nk)),
         ),
         scratch_shapes=[
-            pltpu.VMEM((n_obs,), jnp.float32),
-            pltpu.VMEM((n_obs,), jnp.float32),
+            pltpu.VMEM((n_obs, 1), jnp.float32),
+            pltpu.VMEM((n_obs, 1), jnp.float32),
         ],
     )
     scores = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Skp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, H, 1, Skp), jnp.float32),
         interpret=interpret,
-    )(offs, q_obs, k, full_mask, row_valid)
-    return scores[..., :n_prompt]
+    )(offs, head_major(q_obs), head_major(k, Skp), full_mask, rv)
+    return scores[:, :, 0, :n_prompt]

@@ -348,12 +348,12 @@ def decode_attention(
 
 def paged_decode_attention(
     q: jnp.ndarray,  # (B, H, hd) single query token
-    k_pool: jnp.ndarray,  # (N, block_size, KV, hd) shared block pool
+    k_pool: jnp.ndarray,  # (N, KV, block_size, hd) shared block pool
     v_pool: jnp.ndarray,
-    mask_pool: jnp.ndarray,  # (N, block_size, KV) per-head slot validity
+    mask_pool: jnp.ndarray,  # (N, KV, block_size) per-head slot validity
     table: jnp.ndarray,  # (B, nb) int32 physical block ids (0 = null)
     *,
-    pos_pool: jnp.ndarray | None = None,  # (N, block_size, KV) int32
+    pos_pool: jnp.ndarray | None = None,  # (N, KV, block_size) int32
     new_pos: jnp.ndarray | None = None,  # (B,) query-token positions
     window=None,  # None | python int | traced int32 scalar
     depth: int | None = None,  # static logical cache depth (jnp gather path)
@@ -409,7 +409,7 @@ def paged_decode_attention(
             q, k_pool, v_pool, mask_pool, table, pos_pool=pos_pool,
             new_pos=new_pos, window=window, interpret=_pallas_interpret(),
         )
-    span = table.shape[1] * k_pool.shape[1]
+    span = table.shape[1] * k_pool.shape[2]
     if depth is not None:
         span = min(span, depth)
     if span <= _DIRECT_SEQ:
@@ -458,24 +458,28 @@ def _paged_decode_streaming(q, k_pool, v_pool, mask_pool, table, *,
     analogue of the Pallas two-phase kernel, with the same bounded
     temporaries."""
     B, H, hd = q.shape
-    bs, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, bs = k_pool.shape[1], k_pool.shape[2]
     group = H // KV
     scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     qf = q.astype(jnp.float32)
     cols = jnp.moveaxis(table.astype(jnp.int32), 1, 0)  # (nb, B)
 
+    def rows(pool, tb):  # (B, KV, bs, ...) pool tile -> (B, bs, KV, ...)
+        return jnp.swapaxes(pool[tb], 1, 2)
+
     def tile_logits(tb):
-        kb = _expand_gqa(k_pool[tb], group).astype(jnp.float32)
-        mb = mask_pool[tb]  # (B, bs, KV)
+        kb = _expand_gqa(rows(k_pool, tb), group).astype(jnp.float32)
+        mb = rows(mask_pool, tb)  # (B, bs, KV)
         if window is not None:
-            mb = mb & ((new_pos[:, None, None] - pos_pool[tb]) < window)
+            mb = mb & ((new_pos[:, None, None] - rows(pos_pool, tb))
+                       < window)
         s = jnp.einsum("bhd,bkhd->bhk", qf, kb) * scale
         mh = jnp.repeat(jnp.moveaxis(mb, 2, 1), group, axis=1)  # (B, H, bs)
         return jnp.where(mh, s, NEG_INF), mh
 
     def body(carry, tb):
         m, l, acc = carry
-        vb = _expand_gqa(v_pool[tb], group).astype(jnp.float32)
+        vb = _expand_gqa(rows(v_pool, tb), group).astype(jnp.float32)
         s, mh = tile_logits(tb)
         m_new = jnp.maximum(m, s.max(axis=-1))
         # the explicit where keeps fully-dead rows at l == 0 (m stays

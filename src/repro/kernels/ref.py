@@ -240,23 +240,24 @@ def ssd_scan(
 
 def gather_paged(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     """Flatten a block-table view of a paged pool into the dense cache
-    layout: pool (N, bs, ...) + table (B, nb) -> (B, nb*bs, ...), where
-    logical row ``c`` of sequence ``b`` is ``pool[table[b, c // bs],
-    c % bs]``.  Gathers are exact — the dense view is a bitwise copy of
-    the pooled rows (the paged-vs-dense equivalence lemma)."""
+    layout: pool (N, KV, bs, ...) + table (B, nb) -> (B, nb*bs, KV, ...),
+    where logical row ``c`` of sequence ``b``, kv head ``h`` is
+    ``pool[table[b, c // bs], h, c % bs]``.  Gathers are exact — the dense
+    view is a bitwise copy of the pooled rows (the paged-vs-dense
+    equivalence lemma)."""
     B, nb = table.shape
-    g = pool[table]  # (B, nb, bs, ...)
-    return g.reshape((B, nb * pool.shape[1]) + pool.shape[2:])
+    g = jnp.swapaxes(pool[table], 2, 3)  # (B, nb, bs, KV, ...)
+    return g.reshape((B, nb * pool.shape[2], pool.shape[1]) + pool.shape[3:])
 
 
 def paged_decode_attention(
     q: jnp.ndarray,  # (B, H, hd) single query token
-    k_pool: jnp.ndarray,  # (N, block_size, KV, hd) shared block pool
+    k_pool: jnp.ndarray,  # (N, KV, block_size, hd) shared block pool
     v_pool: jnp.ndarray,
-    mask_pool: jnp.ndarray,  # (N, block_size, KV) per-head validity
+    mask_pool: jnp.ndarray,  # (N, KV, block_size) per-head validity
     table: jnp.ndarray,  # (B, nb) int32 physical block ids (0 = null)
     *,
-    pos_pool: jnp.ndarray | None = None,  # (N, block_size, KV) int32 positions
+    pos_pool: jnp.ndarray | None = None,  # (N, KV, block_size) int32 positions
     new_pos: jnp.ndarray | None = None,  # (B,) query-token absolute positions
     window=None,  # None | python int | traced int32 scalar
     depth: int | None = None,  # static logical depth: slice the gathered view
@@ -292,15 +293,15 @@ def paged_decode_attention(
         mask = mask & ((new_pos[:, None, None] - pos) < window)
     out = decode_attention(q, k, v, kv_mask=mask)
     B, H, _ = q.shape
-    KV = mask_pool.shape[2]
+    KV = mask_pool.shape[1]
     alive = jnp.repeat(mask.any(axis=1), H // KV, axis=1)  # (B, H)
     return jnp.where(alive[..., None], out, 0.0).astype(out.dtype)
 
 
 def paged_decode_masses(
     q: jnp.ndarray,  # (B, H, hd) single query token
-    k_pool: jnp.ndarray,  # (N, block_size, KV, hd) shared block pool
-    mask_pool: jnp.ndarray,  # (N, block_size, KV) per-head validity
+    k_pool: jnp.ndarray,  # (N, KV, block_size, hd) shared block pool
+    mask_pool: jnp.ndarray,  # (N, KV, block_size) per-head validity
     table: jnp.ndarray,  # (B, nb) int32 physical block ids (0 = null)
     *,
     pos_pool: jnp.ndarray | None = None,

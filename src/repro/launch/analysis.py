@@ -1,7 +1,9 @@
 """Compiled-artifact analysis: collective-byte accounting + roofline terms.
 
-This is the §Roofline source (CPU container: we reason from the lowered /
-compiled HLO, not wall-clock).  Hardware constants: TPU v5e.
+This is the §Roofline source: it reasons from the lowered / compiled HLO,
+not wall-clock.  Hardware peaks are keyed by ``device_kind``; the dry-run
+describes TPU v5e chips, and a device missing from ``PEAKS`` is an error,
+never a default.
 """
 
 from __future__ import annotations
@@ -11,10 +13,14 @@ from dataclasses import asdict, dataclass
 
 import numpy as np
 
-# --- TPU v5e ---------------------------------------------------------------
-PEAK_FLOPS_BF16 = 197e12  # per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link (~ per-chip effective injection)
+# Per-chip peaks by ``jax.devices()[0].device_kind``.  TPU v5e: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM); the ICI
+# figure is a per-link effective injection rate (1,600 Gbit/s per chip is
+# the published aggregate).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"  # the chip the dry-run describes
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -99,10 +105,12 @@ def roofline_terms(
     hlo_flops_per_dev: float, hlo_bytes_per_dev: float,
     coll_bytes_per_dev: float, model_flops_global: float,
     peak_bytes: float | None = None,
+    device_kind: str = DRYRUN_DEVICE_KIND,
 ) -> Roofline:
-    compute_s = hlo_flops_per_dev / PEAK_FLOPS_BF16
-    memory_s = hlo_bytes_per_dev / HBM_BW
-    collective_s = coll_bytes_per_dev / ICI_BW
+    peak = PEAKS[device_kind]
+    compute_s = hlo_flops_per_dev / peak["flops_bf16"]
+    memory_s = hlo_bytes_per_dev / peak["hbm_bw"]
+    collective_s = coll_bytes_per_dev / peak["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
@@ -130,21 +138,12 @@ def memory_analysis_dict(compiled) -> dict:
         ma = compiled.memory_analysis()
     except Exception:
         return {}
-    out = {}
-    for k in ("argument_size_in_bytes", "output_size_in_bytes",
-              "temp_size_in_bytes", "generated_code_size_in_bytes",
-              "peak_memory_in_bytes"):
-        v = getattr(ma, k, None)
-        if v is not None:
-            out[k] = int(v)
-    if "peak_memory_in_bytes" not in out and out:
-        # older jaxlib CompiledMemoryStats lacks the attribute: the standard
-        # conservative bound is arguments + outputs + temps + code
-        out["peak_memory_in_bytes"] = sum(
-            out.get(k, 0) for k in ("argument_size_in_bytes",
-                                    "output_size_in_bytes",
-                                    "temp_size_in_bytes",
-                                    "generated_code_size_in_bytes"))
+    out = {k: int(getattr(ma, k))
+           for k in ("argument_size_in_bytes", "output_size_in_bytes",
+                     "temp_size_in_bytes", "generated_code_size_in_bytes")}
+    # CompiledMemoryStats reports no peak: the conservative bound is
+    # arguments + outputs + temps + code
+    out["peak_memory_in_bytes"] = sum(out.values())
     return out
 
 

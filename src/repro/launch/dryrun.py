@@ -30,6 +30,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.common import sharding as sh
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import INPUT_SHAPES, TrainConfig
 from repro.configs import get_config, shape_applicable
 from repro.core.lookahead import init_lookahead_params
@@ -277,6 +278,7 @@ def main():
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--variant", default="", choices=list(VARIANTS))
     args = ap.parse_args()
+    enable_compile_cache()
     res = run_one(args.arch, args.shape, args.mesh, args.out, args.variant)
     sys.exit(0 if res.get("status") in ("ok", "skipped") else 1)
 

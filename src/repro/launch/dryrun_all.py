@@ -11,6 +11,8 @@ import json
 import time
 import traceback
 
+from repro.common.compile_cache import enable_compile_cache
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -19,6 +21,7 @@ def main():
     ap.add_argument("--archs", default="")
     ap.add_argument("--shapes", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.common.config import INPUT_SHAPES
     from repro.configs import ARCH_IDS

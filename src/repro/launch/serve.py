@@ -16,6 +16,8 @@ import warnings
 import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
+from repro.common.sharding import init_params_sharded
 from repro.common.config import EvictionConfig
 from repro.configs import get_config, get_smoke_config
 from repro.core import policies
@@ -104,10 +106,30 @@ def main():
                          "path gets raw events, anything else Chrome "
                          "trace-event JSON (open in https://ui.perfetto.dev)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    streamable = (args.continuous and args.policy not in policies.MULTI_PASS
+                  and args.policy != "full")
+    mesh = None
+    if args.mesh_model > 1:
+        a = cfg.attn
+        if not streamable:
+            ap.error("--mesh-model requires the chunked continuous engine "
+                     "(--continuous with a streamable policy)")
+        if (a is None or a.num_kv_heads % args.mesh_model
+                or a.num_heads % args.mesh_model):
+            heads = None if a is None else (a.num_heads, a.num_kv_heads)
+            ap.error(f"--mesh-model {args.mesh_model} does not divide "
+                     f"{args.arch}'s (q, kv) heads {heads}")
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(model=args.mesh_model)
+        print(f"mesh: {dict(mesh.shape)} over {len(jax.devices())} devices")
     key = jax.random.PRNGKey(args.seed)
-    params = tf.init_params(key, cfg)
+    if mesh is not None:
+        params = init_params_sharded(key, cfg, mesh)
+    else:
+        params = tf.init_params(key, cfg)
     lkv = None
     if cfg.technique_applies and cfg.lookahead:
         lkv = init_lookahead_params(jax.random.PRNGKey(args.seed + 1), cfg,
@@ -117,8 +139,6 @@ def main():
             print(f"loaded lookahead modules from {args.lkv_ckpt}")
 
     rng = np.random.default_rng(args.seed)
-    streamable = (args.continuous and args.policy not in policies.MULTI_PASS
-                  and args.policy != "full")
     if args.prefix_cache_mb and not streamable:
         print("note: --prefix-cache-mb requires the chunked continuous "
               "engine (--continuous with a streamable policy); ignoring it")
@@ -129,24 +149,6 @@ def main():
         print("note: --kv-pool-mb requires the chunked continuous engine "
               "(--continuous with a streamable policy); ignoring it")
         args.kv_pool_mb = 0
-    mesh = None
-    if args.mesh_model > 1:
-        a = cfg.attn
-        if not streamable:
-            print("note: --mesh-model requires the chunked continuous "
-                  "engine (--continuous with a streamable policy); "
-                  "ignoring it")
-        elif (a is None or a.num_kv_heads % args.mesh_model
-              or a.num_heads % args.mesh_model):
-            heads = None if a is None else (a.num_heads, a.num_kv_heads)
-            print(f"note: --mesh-model {args.mesh_model} does not divide "
-                  f"{args.arch}'s (q, kv) heads {heads}; serving "
-                  "single-device")
-        else:
-            from repro.launch.mesh import make_host_mesh
-            mesh = make_host_mesh(model=args.mesh_model)
-            print(f"mesh: {dict(mesh.shape)} over "
-                  f"{len(jax.devices())} devices")
     trace = None  # set on the chunked continuous path
     if args.continuous:
         if args.policy in policies.MULTI_PASS or args.policy == "full":

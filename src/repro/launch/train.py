@@ -38,6 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import io as ckpt
 from repro.common import sharding as sh
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import TrainConfig
 from repro.configs import get_config, get_smoke_config
 from repro.core.lookahead import init_lookahead_params
@@ -178,4 +179,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # only as a program: tests call main() and keep JAX's defaults
+    enable_compile_cache()
     main()

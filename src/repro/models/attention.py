@@ -223,15 +223,8 @@ def layer_window(a: AttentionConfig, is_global) -> "int | jnp.ndarray | None":
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """Version shim: ``jax.shard_map(check_vma=)`` landed after 0.4.x,
-    where the API lives at ``jax.experimental.shard_map`` with the
-    replication check spelled ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pin_activations(h, mesh):
@@ -492,8 +485,8 @@ def _sharded_paged_decode(q1, k1, v1, pool, table, pb, off, write_ok,
     """
     from jax.sharding import PartitionSpec as P
 
-    kvspec = P(None, None, "model", None)  # pool k/v (N, bs, KV, hd)
-    mspec = P(None, None, "model")  # pool pos/mask (N, bs, KV)
+    kvspec = P(None, "model", None, None)  # pool k/v (N, KV, bs, hd)
+    mspec = P(None, "model", None)  # pool pos/mask (N, KV, bs)
     traced_window = window is not None and not isinstance(window, int)
 
     arrs = [q1, k1, v1, pool["k"], pool["v"], pool["pos"], pool["mask"],
@@ -510,10 +503,10 @@ def _sharded_paged_decode(q1, k1, v1, pool, table, pb, off, write_ok,
               npkv, np1, *rest):
         win = rest[0] if traced_window else window
         kvl = kn.shape[-2]
-        pk = pk.at[pbv, offv].set(kn.astype(pk.dtype))
-        pv = pv.at[pbv, offv].set(vn.astype(pv.dtype))
-        ppos = ppos.at[pbv, offv].set(npkv)
-        pmask = pmask.at[pbv, offv].set(
+        pk = pk.at[pbv, :, offv].set(kn.astype(pk.dtype))
+        pv = pv.at[pbv, :, offv].set(vn.astype(pv.dtype))
+        ppos = ppos.at[pbv, :, offv].set(npkv)
+        pmask = pmask.at[pbv, :, offv].set(
             jnp.broadcast_to(wok[:, None], (wok.shape[0], kvl)))
         out = ops.paged_decode_attention(
             qv, pk, pv, pmask, tab, pos_pool=ppos, new_pos=np1,
@@ -589,8 +582,9 @@ def decode_attention_step_paged(
     """One decode step against the *paged* cache (``serving/kv_pool.py``).
 
     ``inp.cache`` holds this layer's slice of the shared block pool
-    ({k, v: (N, bs, KV, hd); pos, mask: (N, bs, KV)}); a slot's logical
-    cache row ``c`` lives at ``(table[b, c // bs], c % bs)``.  The step
+    ({k, v: (N, KV, bs, hd); pos, mask: (N, KV, bs)}); a slot's logical
+    cache row ``c`` of kv head ``h`` lives at
+    ``(table[b, c // bs], h, c % bs)``.  The step
 
     1. appends the new token's K/V at the slot's cursor row — a scatter
        into the slot's own block.  Because the pool is shared, a write
@@ -631,7 +625,7 @@ def decode_attention_step_paged(
     score = pool.get("score")  # (B, depth, KV) decode-eviction masses
     B = h1.shape[0]
     KV = a.num_kv_heads
-    bs = pool["k"].shape[1]
+    bs = pool["k"].shape[2]
     nb = table.shape[1]
     assert depth <= nb * bs, "block table shallower than the logical cache"
     q, k_new, v_new = qkv(p, a, h1, inp)
@@ -663,10 +657,10 @@ def decode_attention_step_paged(
         out = out.reshape(B, 1, a.q_dim)
         out = sharded_wo_linear(out, p["wo"], smesh)
         return out, {"k": pk, "v": pv, "pos": ppos, "mask": pmask}
-    pk = pool["k"].at[pb, off].set(k_new[:, 0].astype(pool["k"].dtype))
-    pv = pool["v"].at[pb, off].set(v_new[:, 0].astype(pool["v"].dtype))
-    ppos = pool["pos"].at[pb, off].set(new_pos[:, 0])
-    pmask = pool["mask"].at[pb, off].set(
+    pk = pool["k"].at[pb, :, off].set(k_new[:, 0].astype(pool["k"].dtype))
+    pv = pool["v"].at[pb, :, off].set(v_new[:, 0].astype(pool["v"].dtype))
+    ppos = pool["pos"].at[pb, :, off].set(new_pos[:, 0])
+    pmask = pool["mask"].at[pb, :, off].set(
         jnp.broadcast_to(write_ok[:, None], (B, KV)))
 
     # -- attend in pool layout: the kernel streams tiles through the

@@ -31,6 +31,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from repro.common.config import EvictionConfig, ModelConfig
@@ -143,7 +144,12 @@ def is_global_flags(cfg: ModelConfig) -> Optional[np.ndarray]:
 def embed(params: dict, cfg: ModelConfig, inputs: jnp.ndarray) -> jnp.ndarray:
     if cfg.embeds_in and jnp.issubdtype(inputs.dtype, jnp.floating):
         return inputs.astype(jnp.dtype(cfg.dtype))
-    return jnp.take(params["embed"], inputs, axis=0)
+    # the gather's output follows the ids' (batch) sharding with the
+    # feature dim whole; under an explicit-axes mesh JAX asks for it to be
+    # stated, since the table itself is vocab-sharded
+    ids = jax.typeof(inputs).sharding
+    return params["embed"].at[inputs].get(
+        out_sharding=ids.update(spec=P(*ids.spec, None)))
 
 
 def unembed(params: dict, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:

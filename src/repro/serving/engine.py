@@ -60,7 +60,7 @@ from repro.serving.batching import (DEFAULT_BUCKETS, ChunkCompileCache,
                                     _bucket_for, _pad_to_bucket)
 from repro.serving.config import (ChunkingConfig, DecodeEvictionConfig,
                                   ServingConfig)
-from repro.serving.kv_pool import KVBlockPool
+from repro.serving.kv_pool import KVBlockPool, from_blocks, to_blocks
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.scheduler import (Request, RequestState, SlotScheduler,
                                      plan_step)
@@ -131,9 +131,8 @@ def paged_sweep(pool: dict, score: jnp.ndarray, table: jnp.ndarray,
     nb = -(-depth // bs)  # blocks covering logical rows [0, depth)
     row = table[slot, :nb]  # (nb,) physical block ids
 
-    def dense(leaf):  # (L, NB, bs, ...) -> (L, depth, ...)
-        g = leaf[:, row]
-        return g.reshape((g.shape[0], nb * bs) + g.shape[3:])[:, :depth]
+    def dense(leaf):  # (L, NB, KV, bs, ...) -> (L, depth, KV, ...)
+        return from_blocks(leaf[:, row])[:, :depth]
 
     k = dense(pool["k"])  # (L, depth, KV, hd)
     v = dense(pool["v"])
@@ -162,9 +161,8 @@ def paged_sweep(pool: dict, score: jnp.ndarray, table: jnp.ndarray,
         cfgpad[1] = (0, rows - x.shape[1])
         return jnp.pad(x, cfgpad)
 
-    def blk(x):  # (L, cap, ...) -> (L, nb_keep, bs, ...)
-        x = pad(x, nb_keep * bs)
-        return x.reshape((x.shape[0], nb_keep, bs) + x.shape[2:])
+    def blk(x):  # (L, cap, KV, ...) -> (L, nb_keep, KV, bs, ...)
+        return to_blocks(x, nb_keep, bs)
 
     keep_ids = row[:nb_keep]
     newpool = {
@@ -921,9 +919,13 @@ class ContinuousEngine(_SlotDecodeMixin):
                            if ops.use_pallas() and static_window
                            else "jnp-fallback"),
             # which paged_decode_attention tier serves this run
-            # (kernel / gather / fallback); "dense" when unpooled
+            # (kernel / gather / fallback); "dense" when unpooled — whose
+            # per-head masks the Pallas decode kernel does not take, so a
+            # kernel backend names the jnp tier it falls to
             "decode_path": (ops.paged_decode_path(self._paged_depth)
-                            if self.pool is not None else "dense"),
+                            if self.pool is not None
+                            else "dense-jnp" if ops.use_pallas()
+                            else "dense"),
             # device mesh this engine serves on (None: single device);
             # bench rows carry it next to decode_path
             "mesh": ({n: int(self.mesh.shape[n])
@@ -1488,14 +1490,12 @@ class ContinuousEngine(_SlotDecodeMixin):
             return
         fn = self._decode_fns.get("retire_gather")
         if fn is None:
-            nb, bs = self._nb_max, self.pool.block_size
+            nb = self._nb_max
             depth = self._paged_depth
 
             def body(pos, mask, row, horizon):
-                def dense(leaf):  # (L, NB, bs, KV) -> (L, depth, KV)
-                    g = leaf[:, row[:nb]]
-                    L = g.shape[0]
-                    return g.reshape(L, nb * bs, -1)[:, :depth]
+                def dense(leaf):  # (L, NB, KV, bs) -> (L, depth, KV)
+                    return from_blocks(leaf[:, row[:nb]])[:, :depth]
 
                 p = dense(pos)
                 # clip at the emitted-token horizon: decode chunks may

@@ -12,15 +12,18 @@ blocks to the free list for the next admission.  Better eviction → fewer
 kept rows → fewer blocks per request → more concurrent requests at a
 fixed ``--kv-pool-mb`` byte budget.
 
-Layout (vLLM-style, per layer)
-------------------------------
-One ``(num_blocks, block_size, kv_heads, head_dim)`` array per layer for
+Layout (head-major blocks, per layer)
+------------------------------------
+One ``(num_blocks, kv_heads, block_size, head_dim)`` array per layer for
 each of K and V (stacked along a leading ``L`` axis so the decode layer
-scan strips it), plus matching ``(num_blocks, block_size, kv_heads)``
+scan strips it), plus matching ``(num_blocks, kv_heads, block_size)``
 ``pos``/``mask`` metadata — eviction keeps *different token positions per
-kv head*, so validity is per-head exactly as in the dense cache.  A
-request's **block table** is a ``(nb,)`` int32 row of physical block ids:
-logical cache row ``c`` lives at ``(table[c // bs], c % bs)``.  The table
+kv head*, so validity is per-head exactly as in the dense cache.  Blocks
+are head-major so that one kv head's rows of a block form a contiguous
+``(block_size, head_dim)`` tile — the shape the TPU decode kernel DMAs.
+A request's **block table** is a ``(nb,)`` int32 row of physical block
+ids: logical cache row ``c`` of kv head ``h`` lives at
+``(table[c // bs], h, c % bs)``.  The table
 is shared across layers (block ``j`` holds the same logical rows of every
 layer), so one table gather reconstructs the whole per-slot view.
 
@@ -47,7 +50,24 @@ import numpy as np
 
 from repro.common.config import ModelConfig
 
-__all__ = ["KVBlockPool"]
+__all__ = ["KVBlockPool", "to_blocks", "from_blocks"]
+
+
+def to_blocks(x: jnp.ndarray, n: int, bs: int) -> jnp.ndarray:
+    """Dense rows (L, rows, KV, ...) -> pool blocks (L, n, KV, bs, ...);
+    rows are zero-padded (or cut) to ``n * bs``."""
+    rows = n * bs
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (0, max(rows - x.shape[1], 0))
+    x = jnp.pad(x, pad)[:, :rows]
+    x = x.reshape((x.shape[0], n, bs) + x.shape[2:])
+    return jnp.swapaxes(x, 2, 3)
+
+
+def from_blocks(g: jnp.ndarray) -> jnp.ndarray:
+    """Pool blocks (L, n, KV, bs, ...) -> dense rows (L, n*bs, KV, ...)."""
+    g = jnp.swapaxes(g, 2, 3)
+    return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
 class KVBlockPool:
@@ -84,10 +104,10 @@ class KVBlockPool:
         assert num_blocks >= 2, "pool too small for even one block"
         self.num_blocks = num_blocks
         N = num_blocks
-        self.k = jnp.zeros((L, N, block_size, KV, hd), dtype)
-        self.v = jnp.zeros((L, N, block_size, KV, hd), dtype)
-        self.pos = jnp.zeros((L, N, block_size, KV), jnp.int32)
-        self.mask = jnp.zeros((L, N, block_size, KV), bool)
+        self.k = jnp.zeros((L, N, KV, block_size, hd), dtype)
+        self.v = jnp.zeros((L, N, KV, block_size, hd), dtype)
+        self.pos = jnp.zeros((L, N, KV, block_size), jnp.int32)
+        self.mask = jnp.zeros((L, N, KV, block_size), bool)
         # tensor-parallel serving: the device arrays shard their kv-head
         # dim over "model" (each shard holds whole blocks of its local
         # head slice), while the host allocator below is head-oblivious —
@@ -242,15 +262,8 @@ class KVBlockPool:
             bs = self.block_size
 
             def write(pool, cache, ids):
-                rows = len(ids) * bs
-
-                def blk(x):  # (L, 1, C, ...) -> (L, n, bs, ...)
-                    x = x[:, 0]
-                    pad = [(0, 0)] * x.ndim
-                    pad[1] = (0, max(rows - x.shape[1], 0))
-                    x = jnp.pad(x, pad)[:, :rows]
-                    return x.reshape((x.shape[0], len(ids), bs)
-                                     + x.shape[2:])
+                def blk(x):  # (L, 1, C, KV, ...) -> (L, n, KV, bs, ...)
+                    return to_blocks(x[:, 0], len(ids), bs)
 
                 return {
                     "k": pool["k"].at[:, ids].set(blk(cache["k"])),
@@ -276,9 +289,8 @@ class KVBlockPool:
             bs = self.block_size
 
             def write(pk, pv, k, v, ids):
-                def blk(x):  # (L, 1, n*bs, KV, hd) -> (L, n, bs, KV, hd)
-                    x = x[:, 0]
-                    return x.reshape((x.shape[0], len(ids), bs) + x.shape[2:])
+                def blk(x):  # (L, 1, n*bs, KV, hd) -> (L, n, KV, bs, hd)
+                    return to_blocks(x[:, 0], len(ids), bs)
 
                 return pk.at[:, ids].set(blk(k)), pv.at[:, ids].set(blk(v))
 

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import transformer as tf
+from repro.serving.kv_pool import from_blocks
 
 __all__ = ["PrefixCache", "PrefixEntry"]
 
@@ -450,8 +451,8 @@ class PrefixCache:
                 bs = self.pool.block_size
 
                 def build(pk, pv, ids, score):
-                    def flat(x):  # (L, n, bs, KV, hd) -> (L, 1, depth, ...)
-                        return x.reshape((x.shape[0], 1, -1) + x.shape[3:])
+                    def flat(x):  # (L, n, KV, bs, hd) -> (L, 1, depth, ...)
+                        return from_blocks(x)[:, None]
 
                     snap = tf.ChunkState(
                         k=flat(pk[:, ids]), v=flat(pv[:, ids]), score=score,

@@ -165,9 +165,9 @@ def test_paged_kernel_matches_oracle(case):
     B, KV, hd, bs = case["B"], case["KV"], case["hd"], case["bs"]
     N, nb, H = case["N"], case["nb"], case["KV"] * case["G"]
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    pk = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pm = jnp.asarray(rng.random((N, bs, KV)) > 0.3)
+    pk = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pm = jnp.asarray(rng.random((N, KV, bs)) > 0.3)
     pm = pm.at[0].set(False)  # the null block is permanently invalid
     # ragged tables: null tails and interleaved null entries
     tbl = np.zeros((B, nb), np.int32)
@@ -188,9 +188,9 @@ def test_paged_kernel_fully_masked_tail_blocks():
     rng = np.random.default_rng(0)
     B, H, KV, hd, bs, N, nb = 2, 4, 2, 32, 8, 6, 4
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    pk = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pm = jnp.ones((N, bs, KV), bool).at[0].set(False)
+    pk = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pm = jnp.ones((N, KV, bs), bool).at[0].set(False)
     pm = pm.at[5].set(False)  # an allocated-but-fully-masked block
     tbl = jnp.asarray([[1, 2, 5, 0], [0, 0, 0, 0]], jnp.int32)
     want = ref.paged_decode_attention(q, pk, pv, pm, tbl)
@@ -206,9 +206,9 @@ def test_ops_paged_dispatch_matches_oracle():
     rng = np.random.default_rng(1)
     B, H, KV, hd, bs, N, nb = 2, 6, 2, 16, 4, 8, 5
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    pk = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pm = jnp.asarray(rng.random((N, bs, KV)) > 0.2).at[0].set(False)
+    pk = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pm = jnp.asarray(rng.random((N, KV, bs)) > 0.2).at[0].set(False)
     tbl = jnp.asarray(rng.integers(0, N, (B, nb)), jnp.int32)
     want = ref.paged_decode_attention(q, pk, pv, pm, tbl)
     got = ops.paged_decode_attention(q, pk, pv, pm, tbl)
@@ -453,10 +453,10 @@ def test_paged_sweep_matches_numpy_topk():
     capacity, depth = 6, 16  # nb=4 blocks, nb_keep=2, pad rows 6..8 dead
     nb, nb_keep = 4, 2
     num_slots, N = 3, 12
-    k = rng.normal(size=(L, N, bs, KV, hd)).astype(np.float32)
-    v = rng.normal(size=(L, N, bs, KV, hd)).astype(np.float32)
-    pos = rng.integers(0, 500, size=(L, N, bs, KV)).astype(np.int32)
-    mask = rng.random((L, N, bs, KV)) < 0.8
+    k = rng.normal(size=(L, N, KV, bs, hd)).astype(np.float32)
+    v = rng.normal(size=(L, N, KV, bs, hd)).astype(np.float32)
+    pos = rng.integers(0, 500, size=(L, N, KV, bs)).astype(np.int32)
+    mask = rng.random((L, N, KV, bs)) < 0.8
     mask[:, 0] = False  # the null block
     score = rng.random((L, num_slots, depth, KV)).astype(np.float32)
     slot = 1
@@ -474,9 +474,9 @@ def test_paged_sweep_matches_numpy_topk():
     row = table[slot]
     keep_ids = row[:nb_keep]
 
-    def dense(x, ids, rows):
-        g = x[:, ids]
-        return g.reshape((L, len(ids) * bs) + x.shape[3:])[:, :rows]
+    def dense(x, ids, rows):  # (L, N, KV, bs, ...) -> (L, rows, KV, ...)
+        g = np.swapaxes(x[:, ids], 2, 3)
+        return g.reshape((L, len(ids) * bs) + g.shape[3:])[:, :rows]
 
     kd, vd = dense(k, row, depth), dense(v, row, depth)
     pd, md = dense(pos, row, depth), dense(mask, row, depth)
@@ -526,9 +526,9 @@ def test_paged_masses_kernel_matches_oracle(case):
     B, KV, hd, bs = case["B"], case["KV"], case["hd"], case["bs"]
     N, nb, H = case["N"], case["nb"], case["KV"] * case["G"]
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    pk = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pm = jnp.asarray(rng.random((N, bs, KV)) > 0.3).at[0].set(False)
+    pk = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pm = jnp.asarray(rng.random((N, KV, bs)) > 0.3).at[0].set(False)
     tbl = np.zeros((B, nb), np.int32)
     for b in range(B):
         n_live = int(rng.integers(0, min(nb, N - 1) + 1))
@@ -537,7 +537,7 @@ def test_paged_masses_kernel_matches_oracle(case):
     tbl = jnp.asarray(tbl)
     kw = {}
     if case["window"]:
-        kw = {"pos_pool": jnp.asarray(rng.integers(0, 50, (N, bs, KV)),
+        kw = {"pos_pool": jnp.asarray(rng.integers(0, 50, (N, KV, bs)),
                                       jnp.int32),
               "new_pos": jnp.asarray(rng.integers(20, 70, (B,)), jnp.int32),
               "window": case["window"]}
@@ -573,15 +573,15 @@ def test_paged_masses_streaming_and_dispatch():
     B, H, KV, hd, bs, N, nb = 2, 6, 2, 16, 4, 11, 5
     depth = 18  # non-multiple of bs: the engine's capacity+interval shape
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    pk = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pm = np.asarray(rng.random((N, bs, KV)) > 0.2)
+    pk = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pm = np.asarray(rng.random((N, KV, bs)) > 0.2)
     pm[0] = False
     # one private block run per sequence, with the engine's depth
     # invariant: rows past ``depth`` are masked False by construction
     # (appends clamp at depth) — the kernel tier's depth slice assumes it
     tbl = 1 + np.arange(B * nb, dtype=np.int32).reshape(B, nb)
-    pm[tbl[:, -1], depth - (nb - 1) * bs:] = False
+    pm[tbl[:, -1], :, depth - (nb - 1) * bs:] = False
     pm, tbl = jnp.asarray(pm), jnp.asarray(tbl)
     want_m = ref.paged_decode_masses(q, pk, pm, tbl)
     out1 = ops._paged_decode_streaming(q, pk, pv, pm, tbl)

@@ -53,10 +53,10 @@ def _paged_inputs(rng, *, B, KV, G, hd, bs, nb, p_valid=0.7, ragged=True):
     and a table with interleaved null entries plus null tails."""
     H, N = KV * G, 2 + B * nb
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    pk = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(N, bs, KV, hd)), jnp.float32)
-    pm = jnp.asarray(rng.random((N, bs, KV)) < p_valid).at[0].set(False)
-    pos = jnp.asarray(rng.integers(0, nb * bs, (N, bs, KV)), jnp.int32)
+    pk = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(N, KV, bs, hd)), jnp.float32)
+    pm = jnp.asarray(rng.random((N, KV, bs)) < p_valid).at[0].set(False)
+    pos = jnp.asarray(rng.integers(0, nb * bs, (N, KV, bs)), jnp.int32)
     tbl = np.zeros((B, nb), np.int32)
     for b in range(B):
         n_live = int(rng.integers(0, nb + 1)) if ragged else nb
@@ -176,11 +176,11 @@ def test_gather_tier_bitwise_equals_dense_reduction(depth_off):
         q, pk, pv, pm, tbl, pos_pool=pos, new_pos=npos, window=window,
         depth=depth)
     # the dense replay: gather, slice to depth, window on gathered pos
-    shp = (B, nb * bs)
-    k = pk[tbl].reshape(shp + pk.shape[2:])[:, :depth]
-    v = pv[tbl].reshape(shp + pv.shape[2:])[:, :depth]
-    m = pm[tbl].reshape(shp + pm.shape[2:])[:, :depth]
-    p = pos[tbl].reshape(shp + pos.shape[2:])[:, :depth]
+    def dense(pool):  # (N, KV, bs, ...) -> (B, depth, KV, ...)
+        g = jnp.swapaxes(pool[tbl], 2, 3)
+        return g.reshape((B, nb * bs) + g.shape[3:])[:, :depth]
+
+    k, v, m, p = dense(pk), dense(pv), dense(pm), dense(pos)
     m = m & ((npos[:, None, None] - p) < window)
     want = ops.decode_attention(q, k, v, kv_mask=m)
     assert np.array_equal(np.asarray(got), np.asarray(want)), \
@@ -346,7 +346,9 @@ def test_engine_reports_decode_path_and_step_time(model):
                                max_new=4)
     _, dense = run_trace(cfg, params, lkv, policy="lookaheadkv",
                          requests=reqs, chunk=chunk, decode_chunk=2)
-    assert dense.stats["decode_path"] == "dense"
+    # a kernel backend names the jnp tier its per-head-mask decode takes
+    assert dense.stats["decode_path"] == ("dense-jnp" if ops.use_pallas()
+                                          else "dense")
     pool = KVBlockPool(cfg, block_size=16, num_blocks=128)
     _, paged = run_trace(cfg, params, lkv, policy="lookaheadkv",
                          requests=reqs, chunk=chunk, decode_chunk=2,

@@ -1,0 +1,126 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e chip.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+block shapes whose minor dims are not (8, 128)-tileable, 1-D scratch,
+fast-memory overruns.  These tests lower and compile each serving-path
+kernel at qwen2-1.5b widths (H=12, KV=2, head_dim 128, bf16) — and the
+windowed paged variant at gemma3-1b widths (head_dim 256, KV=1) — for a
+``v5e:2x2`` topology that the TPU compiler describes without a chip
+attached.  Nothing runs; a compile that passes is not a chip run.
+
+The topology is described inside a module fixture (never at import), so
+pytest-xdist workers all collect the same tests and only the worker given
+this file loads the TPU compiler.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.chunk_attention import (chunk_attention_masses_pallas,
+                                           chunk_attention_pallas)
+from repro.kernels.decode_attention import decode_attention_pallas
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.lookahead_score import lookahead_score_pallas
+from repro.kernels.paged_attention import (paged_decode_attention_pallas,
+                                           paged_decode_masses_pallas)
+
+# qwen2-1.5b attention widths
+H, KV, HD = 12, 2, 128
+BF16 = jnp.bfloat16
+# pool block size chip_smoke.py serves with, and the launcher's default
+POOL_BLOCK_SIZES = (64, 16)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler installed here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-topology executable can be written to the persistent
+    # cache but never read back without a chip: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_chunk_attention_compiles(one_chip, fused):
+    C, K = 256, 4096
+
+    def f(q, k, v, off, nt):
+        if fused:
+            return chunk_attention_masses_pallas(q, k, v, off, nt,
+                                                 block_k=1024)
+        return chunk_attention_pallas(q, k, v, off, block_k=1024)
+
+    _compile(f, one_chip, ((1, C, H, HD), BF16), ((1, K, KV, HD), BF16),
+             ((1, K, KV, HD), BF16), ((), jnp.int32), ((), jnp.int32))
+
+
+def test_lookahead_score_compiles(one_chip):
+    n_obs, n_prompt = 32, 4000
+
+    def f(q, k, off, rv):
+        return lookahead_score_pallas(q, k, n_prompt, q_offset=off,
+                                      row_valid=rv, block_k=2048)
+
+    _compile(f, one_chip, ((1, n_obs, H, HD), BF16),
+             ((1, n_prompt + n_obs, KV, HD), BF16), ((), jnp.int32),
+             ((1, n_obs), jnp.bool_))
+
+
+@pytest.mark.parametrize("bs", POOL_BLOCK_SIZES)
+@pytest.mark.parametrize("fused", [False, True])
+def test_paged_decode_compiles(one_chip, bs, fused):
+    B, N, nb = 4, 1024, 4608 // bs
+    kern = paged_decode_masses_pallas if fused \
+        else paged_decode_attention_pallas
+
+    _compile(kern, one_chip, ((B, H, HD), BF16), ((N, KV, bs, HD), BF16),
+             ((N, KV, bs, HD), BF16), ((N, KV, bs), jnp.bool_),
+             ((B, nb), jnp.int32))
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_paged_decode_windowed_compiles(one_chip, fused):
+    # gemma3-1b widths: 4 q heads over one kv head of 256, window 512
+    B, N, bs, nb, h, hd = 4, 512, 64, 32, 4, 256
+    kern = paged_decode_masses_pallas if fused \
+        else paged_decode_attention_pallas
+
+    def f(q, kp, vp, mp, pp, tbl, npos):
+        return kern(q, kp, vp, mp, tbl, pos_pool=pp, new_pos=npos,
+                    window=512)
+
+    _compile(f, one_chip, ((B, h, hd), BF16), ((N, 1, bs, hd), BF16),
+             ((N, 1, bs, hd), BF16), ((N, 1, bs), jnp.bool_),
+             ((N, 1, bs), jnp.int32), ((B, nb), jnp.int32),
+             ((B,), jnp.int32))
+
+
+def test_flash_and_dense_decode_compile(one_chip):
+    S = 1024
+    _compile(lambda q, k, v: flash_attention_pallas(q, k, v), one_chip,
+             ((1, S, H, HD), BF16), ((1, S, KV, HD), BF16),
+             ((1, S, KV, HD), BF16))
+    _compile(lambda q, k, v, m: decode_attention_pallas(q, k, v, kv_mask=m),
+             one_chip, ((4, H, HD), BF16), ((4, 3000, KV, HD), BF16),
+             ((4, 3000, KV, HD), BF16), ((4, 3000), jnp.bool_))
