@@ -154,6 +154,7 @@ def chunk_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, C, hd), q.dtype),
         interpret=interpret,
+        name="chunk_attention",
     )(offs, head_major(q), head_major(k, Kp), head_major(v, Kp))
     return jnp.swapaxes(out, 1, 2)
 
@@ -283,5 +284,6 @@ def chunk_attention_masses_pallas(
             jax.ShapeDtypeStruct((B, H, 1, Kp), jnp.float32),
         ],
         interpret=interpret,
+        name="chunk_attention_masses",
     )(offs, head_major(q), head_major(k, Kp), head_major(v, Kp))
     return jnp.swapaxes(out, 1, 2), masses[:, :, 0, :K]

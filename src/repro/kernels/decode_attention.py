@@ -97,5 +97,6 @@ def decode_attention_pallas(
             pltpu.VMEM((G, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(q.reshape(B, KV, G, hd), head_major(k, Skp), head_major(v, Skp), mask)
     return out.reshape(B, H, hd)

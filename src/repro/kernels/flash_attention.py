@@ -119,5 +119,6 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(head_major(q), head_major(k), head_major(v))
     return jnp.swapaxes(out, 1, 2)

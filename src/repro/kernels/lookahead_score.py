@@ -162,5 +162,6 @@ def lookahead_score_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, Skp), jnp.float32),
         interpret=interpret,
+        name="lookahead_score",
     )(offs, head_major(q_obs), head_major(k, Skp), full_mask, rv)
     return scores[:, :, 0, :n_prompt]

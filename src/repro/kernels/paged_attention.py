@@ -199,6 +199,7 @@ def _paged_call(q, k_pool, v_pool, mask_pool, table, *, pos_pool, new_pos,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name="paged_attention_masses" if masses else "paged_attention",
     )(*prefetch, *args)
     out = res[0].reshape(B, H, hd)
     if not masses:

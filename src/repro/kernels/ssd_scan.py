@@ -119,5 +119,6 @@ def ssd_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((block_nh, hd, ds), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt, A, Bm, Cm, initial_state)
     return y, hout

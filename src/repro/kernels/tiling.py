@@ -9,6 +9,7 @@ lane-aligned tiles.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -25,8 +26,11 @@ def key_tiling(K: int, block_k: int) -> tuple[int, int]:
 
 
 def head_major(x: jnp.ndarray, rows: int | None = None) -> jnp.ndarray:
-    """(B, S, heads, hd) -> (B, heads, S, hd), zero-padding S to ``rows``."""
-    x = jnp.swapaxes(x, 1, 2)
-    if rows is not None and rows > x.shape[2]:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, rows - x.shape[2]), (0, 0)))
+    """(B, S, heads, hd) -> (B, heads, S, hd), zero-padding S to ``rows``.
+    Its ops run under the ``kv_head_major`` scope, the name a device trace
+    finds this re-layout (a full copy of the operand) by."""
+    with jax.named_scope("kv_head_major"):
+        x = jnp.swapaxes(x, 1, 2)
+        if rows is not None and rows > x.shape[2]:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, rows - x.shape[2]), (0, 0)))
     return x

@@ -159,10 +159,11 @@ def chunk_prefill_attention(
     start index and corrupt earlier chunks' keys.
     """
     q, k, v = qkv(p, a, h, inp, lora=lora, lora_scale=lora_scale)
-    k_buf = jax.lax.dynamic_update_slice(
-        k_buf, k.astype(k_buf.dtype), (0, q_offset, 0, 0))
-    v_buf = jax.lax.dynamic_update_slice(
-        v_buf, v.astype(v_buf.dtype), (0, q_offset, 0, 0))
+    with jax.named_scope("kv_buffer_write"):
+        k_buf = jax.lax.dynamic_update_slice(
+            k_buf, k.astype(k_buf.dtype), (0, q_offset, 0, 0))
+        v_buf = jax.lax.dynamic_update_slice(
+            v_buf, v.astype(v_buf.dtype), (0, q_offset, 0, 0))
     window = layer_window(a, is_global)
     masses = None
     smesh = model_shard_mesh(inp.mesh, a)

@@ -789,23 +789,25 @@ def _chunk_observation_pass(
         lp = x["p"]
         lora_l = x.get("lora")
         flag = x.get("flag", True)
-        h = attn_mod.pin_activations(h, mesh)
-        u = rms_norm(h, lp["ln1"], cfg.norm_eps)
-        out, q, k_buf, v_buf, _ = attn_mod.chunk_prefill_attention(
-            lp["attn"], a, u, inp, x["k"], x["v"], q_offset=n_total,
-            is_global=flag,
-            lora=None if lora_l is None else lora_l.get("attn"),
-            lora_scale=ls,
-        )
-        h = h + out
-        h, _ = _ffn_residual(h, lp, cfg, lora_l=lora_l, lora_mask=lmask,
-                             ls=ls, smesh=smesh)
+        with jax.named_scope("lookahead_rows"):
+            h = attn_mod.pin_activations(h, mesh)
+            u = rms_norm(h, lp["ln1"], cfg.norm_eps)
+            out, q, k_buf, v_buf, _ = attn_mod.chunk_prefill_attention(
+                lp["attn"], a, u, inp, x["k"], x["v"], q_offset=n_total,
+                is_global=flag,
+                lora=None if lora_l is None else lora_l.get("attn"),
+                lora_scale=ls,
+            )
+            h = h + out
+            h, _ = _ffn_residual(h, lp, cfg, lora_l=lora_l, lora_mask=lmask,
+                                 ls=ls, smesh=smesh)
         # the masked streaming primitive scores the observation rows over
         # the whole buffer (mean over the n_obs rows, traced row base)
-        masses = attn_mod.sharded_lookahead_score(
-            q, k_buf, K, q_offset=n_total, window=layer_window(a, flag),
-            smesh=smesh,
-        )
+        with jax.named_scope("lookahead_scores"):
+            masses = attn_mod.sharded_lookahead_score(
+                q, k_buf, K, q_offset=n_total, window=layer_window(a, flag),
+                smesh=smesh,
+            )
         return h, {"k": k_buf, "v": v_buf, "obs": masses}
 
     _, ys = jax.lax.scan(body, h, xs)
@@ -865,31 +867,34 @@ def prefill_finalize(
 
     def body(carry, x):
         flag = x.get("flag", True)
-        if policy in OBS_POLICIES:
-            s_kv = scoring.finalize_layer_scores(
-                policy, x["k"], n_total,
-                acc_l=x.get("acc"), cnt=state.score.cnt,
-                qbuf_l=x.get("qbuf"), obs_masses_l=x.get("obs"),
-                num_kv_heads=a.num_kv_heads,
-                pool_kernel=lk.pool_kernel if lk else 7,
-                window_size=lk.window_size if lk else 32,
-                window=layer_window(a, flag),
-                smesh=smesh,
+        with jax.named_scope("lookahead_scores"):
+            if policy in OBS_POLICIES:
+                s_kv = scoring.finalize_layer_scores(
+                    policy, x["k"], n_total,
+                    acc_l=x.get("acc"), cnt=state.score.cnt,
+                    qbuf_l=x.get("qbuf"), obs_masses_l=x.get("obs"),
+                    num_kv_heads=a.num_kv_heads,
+                    pool_kernel=lk.pool_kernel if lk else 7,
+                    window_size=lk.window_size if lk else 32,
+                    window=layer_window(a, flag),
+                    smesh=smesh,
+                )
+            else:
+                s_kv = ev.position_scores(
+                    policy, K, B, a.num_kv_heads, sink=evict.sink,
+                    seeds=seeds,
+                )
+                s_kv = jnp.where(key_mask[:, None, :], s_kv, -1e30)
+        with jax.named_scope("topk_evict"):
+            hb = None
+            if adaptive:
+                hb = ev.adaptive_head_budgets(
+                    jnp.maximum(s_kv, 0.0), evict.budget, capacity)
+            cache_l = ev.evict_layer(
+                s_kv, x["k"], x["v"], capacity,
+                layer_budget=None if adaptive else x.get("budget"),
+                head_budgets=hb, extra_slots=extra_slots, key_mask=key_mask,
             )
-        else:
-            s_kv = ev.position_scores(
-                policy, K, B, a.num_kv_heads, sink=evict.sink, seeds=seeds,
-            )
-            s_kv = jnp.where(key_mask[:, None, :], s_kv, -1e30)
-        hb = None
-        if adaptive:
-            hb = ev.adaptive_head_budgets(
-                jnp.maximum(s_kv, 0.0), evict.budget, capacity)
-        cache_l = ev.evict_layer(
-            s_kv, x["k"], x["v"], capacity,
-            layer_budget=None if adaptive else x.get("budget"),
-            head_budgets=hb, extra_slots=extra_slots, key_mask=key_mask,
-        )
         return carry, dict(cache_l._asdict())
 
     _, attn_cache = jax.lax.scan(body, 0, xs)

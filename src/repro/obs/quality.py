@@ -26,6 +26,7 @@ the property ``benchmarks/bench_obs.py`` asserts.
 from __future__ import annotations
 
 import functools
+from contextlib import nullcontext
 from typing import Optional
 
 import numpy as np
@@ -190,18 +191,16 @@ class DriftMonitor:
         if not self._ring:
             return None
         tr = self._trace
-        if tr is not None:
-            tr.begin("drift_eval", tr.ENGINE, records=len(self._ring))
-        ovs: list[float] = []
-        for x, y in self._ring:
-            gt = self.gt_head_scores(x, y)
-            pred = self.pred_head_scores(x)
-            ovs.extend(kept_overlaps(pred, gt, self.budget))
-        self.last_overlap = float(np.mean(ovs))
-        self.evals += 1
-        if self._metrics is not None:
-            self._metrics.counter("lookahead_drift_evals").inc()
-        if tr is not None:
-            tr.end("drift_eval", tr.ENGINE,
-                   overlap=self.last_overlap)
+        with (tr.span("drift_eval", tr.ENGINE, records=len(self._ring))
+              if tr is not None else nullcontext({})) as end:
+            ovs: list[float] = []
+            for x, y in self._ring:
+                gt = self.gt_head_scores(x, y)
+                pred = self.pred_head_scores(x)
+                ovs.extend(kept_overlaps(pred, gt, self.budget))
+            self.last_overlap = float(np.mean(ovs))
+            self.evals += 1
+            if self._metrics is not None:
+                self._metrics.counter("lookahead_drift_evals").inc()
+            end["overlap"] = self.last_overlap
         return self.last_overlap

@@ -21,6 +21,18 @@ A preempted request's spans are *closed* at preemption (outcome
 ``replay_of`` arg carries the original admission's ``admission_seq`` —
 the replay ↔ original link the span-invariant tests assert.
 
+The engine track holds the serving loop's host phases, so that every
+stretch of the loop has a name: ``schedule``, ``admit`` (around
+``finalize``, ``admit_sync``, ``place`` and ``first_token``),
+``evict_sweep``, ``append_blocks``, ``decode_chunk`` (dispatch),
+``token_sync``, ``collect`` and ``wait_arrival``, plus a ``clock`` span
+about once a second.  Every span opened with ``span`` — each phase, and
+the request track's ``prefill_chunk`` (dispatch), ``finalize``
+(dispatch), ``prefix_probe``, ``paged_sweep`` and ``harvest`` — is also a
+``jax.profiler.TraceAnnotation``, so under ``jax.profiler.trace`` the
+engine thread's phases lie on the profiler's clock beside the device's
+ops.  The long-lived ``request`` and ``decode`` spans are recorder-only.
+
 **Device-time attribution.**  Span end timestamps are host stamps; under
 JAX async dispatch a bare stamp measures *dispatch*.  The engine
 therefore blocks on the spanned computation's output arrays before
@@ -87,17 +99,30 @@ class TraceRecorder:
 
     @contextmanager
     def span(self, name: str, tid: str, sync_on=None, **args):
-        """Timed span; blocks on ``sync_on`` (any jax pytree) before the
-        end stamp when the recorder is sync-mode — the device-time
-        attribution fix for async dispatch."""
+        """Timed span that is also a ``jax.profiler.TraceAnnotation`` of the
+        same name and args, so a profiler trace shows it on the calling
+        thread beside the device's ops.  Blocks on ``sync_on`` (any jax
+        pytree) before the end stamp when the recorder is sync-mode — the
+        device-time attribution fix for async dispatch.  Yields a dict:
+        what the caller puts in it becomes the end event's args."""
+        import jax
+
+        end: dict = {}
         self.begin(name, tid, **args)
-        try:
-            yield
-        finally:
-            if sync_on is not None and self.sync:
-                import jax
-                jax.block_until_ready(sync_on)
-            self.end(name, tid)
+        with jax.profiler.TraceAnnotation(name, **args):
+            try:
+                yield end
+            finally:
+                if sync_on is not None and self.sync:
+                    jax.block_until_ready(sync_on)
+                self.end(name, tid, **end)
+
+    def clock(self) -> None:
+        """A zero-length ``clock`` span on the engine track whose profiler
+        annotation carries this recorder's own timestamp (``recorder_us``):
+        it maps the recorder's events onto the profiler's clock."""
+        with self.span("clock", ENGINE_TRACK, recorder_us=self.now_us()):
+            pass
 
     # -- export --------------------------------------------------------------
     def to_jsonl(self, path: str) -> None:

@@ -40,6 +40,7 @@ from __future__ import annotations
 import time
 import warnings
 from collections.abc import Mapping
+from contextlib import nullcontext
 from functools import partial
 from typing import Optional
 
@@ -68,6 +69,12 @@ from repro.serving.scheduler import (Request, RequestState, SlotScheduler,
 __all__ = ["Request", "RequestState", "ServingEngine", "ContinuousEngine",
            "BucketedEngine", "ServingConfig", "DecodeEvictionConfig",
            "ChunkingConfig", "cache_bytes", "paged_sweep"]
+
+# what a host phase enters when no recorder is attached: no span, no
+# profiler annotation
+_NO_SPAN = nullcontext()
+# engine seconds between the trace's ``clock`` spans
+CLOCK_EVERY_S = 1.0
 
 
 def cache_bytes(cfg: ModelConfig, capacity: int, n_in: int) -> dict:
@@ -301,6 +308,15 @@ class _SlotDecodeMixin:
     #: decode chunk lengths we are willing to compile
     _CHUNK_SIZES = (1, 2, 4, 8, 16)
 
+    def _phase(self, name: str, tid: Optional[str] = None, **args):
+        """A host phase: a recorder span (and profiler annotation) on
+        ``tid``, the engine track by default, when a recorder is
+        attached; else a shared no-op context."""
+        tr = getattr(self, "trace", None)
+        if tr is None:
+            return _NO_SPAN
+        return tr.span(name, tid or tr.ENGINE, **args)
+
     def _decode_fn(self, steps: int):
         fn = self._decode_fns.get(steps)
         if fn is None:
@@ -362,11 +378,8 @@ class _SlotDecodeMixin:
                 # from live traffic (deprecated engines lack the hook)
                 h = getattr(self, "harvest", None)
                 if h is not None:
-                    if tr is not None:
-                        tr.begin("harvest", tid)
-                    h.on_retire(r)
-                    if tr is not None:
-                        tr.end("harvest", tid)
+                    with self._phase("harvest", tid):
+                        h.on_retire(r)
                 # lookahead drift monitor (repro.obs.quality): same moment,
                 # same reason — the generated future is in hand
                 d = getattr(self, "drift", None)
@@ -695,12 +708,6 @@ class ContinuousEngine(_SlotDecodeMixin):
         self._m_prefill_chunks = m.counter(
             "serving_prefill_chunks_total",
             "Prefill chunk programs dispatched.")
-        self._m_prefill_seconds = m.counter(
-            "serving_prefill_seconds_total",
-            "Wall seconds spent in prefill chunk programs. " + sync_note)
-        self._m_prefill_chunk_hist = m.histogram(
-            "serving_prefill_chunk_seconds",
-            "Per-prefill-chunk wall time distribution. " + sync_note)
         self._m_decode_chunks = m.counter(
             "serving_decode_chunks_total",
             "Slot-batched decode chunk programs dispatched.")
@@ -948,31 +955,43 @@ class ContinuousEngine(_SlotDecodeMixin):
 
     def _run_loop(self, sched, tok, live, active, remaining, last_emit,
                   t0) -> None:
+        # every host stretch of an iteration runs under a named phase
+        # (``_phase``): with a recorder attached, the device trace's idle
+        # gaps can be charged to what the host was doing
+        phase = self._phase
         pf: Optional[_InflightPrefill] = None
         since_decode = 0
+        next_clock = 0.0
         try:
             while sched.has_work() or pf is not None:
                 now = time.perf_counter() - t0
-                if pf is None:
-                    req = sched.next_request(now)
-                    if req is not None:
-                        pf = self._begin_prefill(req)
+                if self.trace is not None and now >= next_clock:
+                    self.trace.clock()
+                    next_clock = now + CLOCK_EVERY_S
+                with phase("schedule"):
+                    if pf is None:
+                        req = sched.next_request(now)
+                        if req is not None:
+                            pf = self._begin_prefill(req)
+                    if pf is not None:
+                        steps = self._pick_chunk(remaining, active) \
+                            if active.any() else max(self._chunks)
+                        _, n_chunks = plan_step(
+                            token_budget=self.token_budget, chunk=self.chunk,
+                            n_active=int(active.sum()), decode_steps=steps,
+                            prefill_pending=True,
+                        )
                 if pf is not None:
-                    steps = self._pick_chunk(remaining, active) if active.any() \
-                        else max(self._chunks)
-                    _, n_chunks = plan_step(
-                        token_budget=self.token_budget, chunk=self.chunk,
-                        n_active=int(active.sum()), decode_steps=steps,
-                        prefill_pending=True,
-                    )
                     for _ in range(n_chunks):
                         if pf.s < pf.n:  # a full prefix-cache hit has no chunks
                             self._prefill_step(pf)
                             if active.any():  # only live slots can be stalled
                                 since_decode += 1
                         if pf.s >= pf.n:
-                            tok, live = self._admit(pf, sched, tok, live, active,
-                                                    remaining, last_emit, t0)
+                            with phase("admit"):
+                                tok, live = self._admit(
+                                    pf, sched, tok, live, active, remaining,
+                                    last_emit, t0)
                             pf = None
                             break
                 self._m_max_concurrency.max(len(sched.running))
@@ -987,8 +1006,9 @@ class ContinuousEngine(_SlotDecodeMixin):
                             # cap the chunk so no active cursor can
                             # overrun the depth mid-chunk (the sweep
                             # trigger is checked only between chunks)
-                            self._decode_evict_sweep(sched, active,
-                                                     remaining, last_emit)
+                            with phase("evict_sweep"):
+                                self._decode_evict_sweep(sched, active,
+                                                         remaining, last_emit)
                             if not active.any():
                                 continue
                             room = int(np.min(
@@ -998,86 +1018,87 @@ class ContinuousEngine(_SlotDecodeMixin):
                         # grow every live slot's append blocks before the
                         # chunk runs — a missing block would null-route the
                         # appends; preempts the latest admission when dry
-                        self._ensure_append_blocks(sched, active, remaining,
-                                                   last_emit, steps)
+                        with phase("append_blocks"):
+                            self._ensure_append_blocks(sched, active,
+                                                       remaining, last_emit,
+                                                       steps)
                         if not active.any():
                             continue  # every live slot was preempted
                         dispatched = active.copy()
                         fn = self._decode_fn_paged(steps)
-                        tr = self.trace
-                        if tr is not None:
-                            tr.begin("decode_chunk", tr.ENGINE, steps=steps,
-                                     slots=int(active.sum()))
                         t_dec = time.perf_counter()
-                        # _snapshot the host mirrors before handing them
-                        # to jax: dispatch is async and the host->device
-                        # staging of an argument can happen after this
-                        # call returns, so a buffer we mutate in place
-                        # below (cursor/npos advance, retirement
-                        # bookkeeping) would race the device read
-                        if self._score_dev is not None:
-                            tok, ptree, toks, self._score_dev = fn(
-                                self.params, tok, self._table_dev,
-                                _snapshot(self._cursor_h),
-                                _snapshot(self._npos_h[:, None]),
-                                self.pool.tree(), _snapshot(active),
-                                _snapshot(self._seeds_h), self._score_dev)
-                        else:
-                            tok, ptree, toks = fn(
-                                self.params, tok, self._table_dev,
-                                _snapshot(self._cursor_h),
-                                _snapshot(self._npos_h[:, None]),
-                                self.pool.tree(), _snapshot(active),
-                                _snapshot(self._seeds_h))
-                        if self._sync_timers:
-                            # device-time attribution: block on the whole
-                            # output pytree so the stamp below measures
-                            # execution, not dispatch
-                            jax.block_until_ready((tok, ptree, toks))
-                        self.pool.set_tree(ptree)
-                        # mirror the device advance rule exactly: slots
-                        # active at dispatch move `steps`, cursors clamp
-                        self._cursor_h[dispatched] = np.minimum(
-                            self._cursor_h[dispatched] + steps,
-                            self._paged_depth)
-                        self._npos_h[dispatched] += steps
+                        with phase("decode_chunk", steps=steps,
+                                   slots=int(active.sum())):
+                            # _snapshot the host mirrors before handing
+                            # them to jax: dispatch is async and the
+                            # host->device staging of an argument can
+                            # happen after this call returns, so a buffer
+                            # we mutate in place below (cursor/npos
+                            # advance, retirement bookkeeping) would race
+                            # the device read
+                            if self._score_dev is not None:
+                                tok, ptree, toks, self._score_dev = fn(
+                                    self.params, tok, self._table_dev,
+                                    _snapshot(self._cursor_h),
+                                    _snapshot(self._npos_h[:, None]),
+                                    self.pool.tree(), _snapshot(active),
+                                    _snapshot(self._seeds_h), self._score_dev)
+                            else:
+                                tok, ptree, toks = fn(
+                                    self.params, tok, self._table_dev,
+                                    _snapshot(self._cursor_h),
+                                    _snapshot(self._npos_h[:, None]),
+                                    self.pool.tree(), _snapshot(active),
+                                    _snapshot(self._seeds_h))
+                            if self._sync_timers:
+                                # device-time attribution: block on the
+                                # whole output pytree so the span measures
+                                # execution, not dispatch
+                                jax.block_until_ready((tok, ptree, toks))
+                            self.pool.set_tree(ptree)
+                            # mirror the device advance rule exactly:
+                            # slots active at dispatch move `steps`,
+                            # cursors clamp
+                            self._cursor_h[dispatched] = np.minimum(
+                                self._cursor_h[dispatched] + steps,
+                                self._paged_depth)
+                            self._npos_h[dispatched] += steps
                     else:
                         fn = self._decode_fn(steps)
-                        tr = self.trace
-                        if tr is not None:
-                            tr.begin("decode_chunk", tr.ENGINE, steps=steps,
-                                     slots=int(active.sum()))
                         t_dec = time.perf_counter()
-                        tok, live, toks = fn(self.params, tok, live,
-                                             jnp.asarray(active),
-                                             jnp.asarray(self._seeds_h))
-                        if self._sync_timers:
-                            jax.block_until_ready((tok, live, toks))
-                    toks_np = np.asarray(toks)  # device sync: tokens landed
+                        with phase("decode_chunk", steps=steps,
+                                   slots=int(active.sum())):
+                            tok, live, toks = fn(self.params, tok, live,
+                                                 jnp.asarray(active),
+                                                 jnp.asarray(self._seeds_h))
+                            if self._sync_timers:
+                                jax.block_until_ready((tok, live, toks))
+                    with phase("token_sync"):
+                        toks_np = np.asarray(toks)  # device sync: tokens landed
                     dt = time.perf_counter() - t_dec
-                    if tr is not None:
-                        tr.end("decode_chunk", tr.ENGINE)
                     self._m_decode_chunks.inc()
                     self._m_decode_steps.inc(steps)
                     self._m_decode_seconds.inc(dt)
                     self._m_decode_chunk_hist.observe(dt)
-                    self._collect(toks_np, steps, sched, active,
-                                  remaining, last_emit, t0)
+                    with phase("collect"):
+                        self._collect(toks_np, steps, sched, active,
+                                      remaining, last_emit, t0)
                 elif pf is None:
-                    now2 = time.perf_counter() - t0
-                    if sched.has_arrived(now2):
-                        if self.pool is not None and not sched.running:
-                            # nothing can retire to free blocks: reclaim
-                            # prefix-cache pins or fail loudly instead of
-                            # spinning on a gated queue head
-                            self._reclaim_for_head(sched)
-                        continue  # a request is admissible right now
-                    nxt = sched.next_arrival()
-                    if nxt is None:
-                        break  # defensive: nothing queued, nothing running
-                    wait = nxt - (time.perf_counter() - t0)
-                    if wait > 0:
-                        time.sleep(min(wait, 0.05))
+                    with phase("wait_arrival"):
+                        now2 = time.perf_counter() - t0
+                        if sched.has_arrived(now2):
+                            if self.pool is not None and not sched.running:
+                                # nothing can retire to free blocks:
+                                # reclaim prefix-cache pins or fail loudly
+                                # instead of spinning on a gated queue head
+                                self._reclaim_for_head(sched)
+                            continue  # a request is admissible right now
+                        nxt = sched.next_arrival()
+                        if nxt is None:
+                            break  # defensive: nothing queued, nothing running
+                        wait = nxt - (time.perf_counter() - t0)
+                        if wait > 0:
+                            time.sleep(min(wait, 0.05))
         finally:
             # an exception escaping the loop must not leak a trie pin: the
             # cache outlives run() calls, and a leaked ref would make the
@@ -1107,92 +1128,83 @@ class ContinuousEngine(_SlotDecodeMixin):
                 self._uid_seq[req.uid] = seq
             tr.begin("request", tid, **args)
         if self.prefix_cache is not None:
-            if tr is not None:
-                tr.begin("prefix_probe", tid)
-            # only snapshots streamed under this request's KV-buffer rung
-            # match — the condition for a bitwise-identical resume
-            entry = self.prefix_cache.lookup(req.prompt, capacity=cap)
-            if entry is not None:
-                # materialize before pinning: if it raises there is no
-                # _InflightPrefill yet, so a pin taken here could never be
-                # released by the loop's finally
-                state, logits = self.prefix_cache.materialize(entry, cap)
-                self.prefix_cache.acquire(entry)
-                pf = _InflightPrefill(req, state, n)
-                pf.s = entry.depth
-                pf.logits = logits  # the boundary chunk's next-token logits
-                pf.tip = entry
-                req.cached_prefix_tokens = entry.depth
-                self._m_prefix_hits.inc()
-                self._m_prefix_tokens_skipped.inc(entry.depth)
-                if tr is not None:
-                    tr.end("prefix_probe", tid, hit=True, depth=entry.depth)
+            with self._phase("prefix_probe", tid) as end:
+                # only snapshots streamed under this request's KV-buffer
+                # rung match — the condition for a bitwise-identical resume
+                entry = self.prefix_cache.lookup(req.prompt, capacity=cap)
+                hit = entry is not None
+                if hit:
+                    # materialize before pinning: if it raises there is no
+                    # _InflightPrefill yet, so a pin taken here could
+                    # never be released by the loop's finally
+                    state, logits = self.prefix_cache.materialize(entry, cap)
+                    self.prefix_cache.acquire(entry)
+                    pf = _InflightPrefill(req, state, n)
+                    pf.s = entry.depth
+                    pf.logits = logits  # the boundary chunk's logits
+                    pf.tip = entry
+                    req.cached_prefix_tokens = entry.depth
+                    self._m_prefix_hits.inc()
+                    self._m_prefix_tokens_skipped.inc(entry.depth)
+                else:
+                    self._m_prefix_misses.inc()
+                if end is not None:
+                    end.update(hit=hit, depth=entry.depth if hit else 0)
+            if hit:
                 return pf
-            self._m_prefix_misses.inc()
-            if tr is not None:
-                tr.end("prefix_probe", tid, hit=False, depth=0)
         state = tf.init_chunk_state(self.cfg, self.policy, 1, cap)
         return _InflightPrefill(req, state, n)
 
     def _prefill_step(self, pf: _InflightPrefill) -> None:
-        blk = np.zeros((1, self.chunk), np.int32)
-        seg = pf.req.prompt[pf.s:pf.s + self.chunk]
-        blk[0, :len(seg)] = seg
-        fn = self.chunk_cache.get("chunk", self.chunk, 1, self.policy)
-        tr = self.trace
-        if tr is not None:
-            tr.begin("prefill_chunk", request_track(pf.req.uid), s=pf.s)
-        t_pf = time.perf_counter()
-        pf.state, pf.logits = fn(self.params, pf.state, jnp.asarray(blk),
-                                 jnp.asarray(pf.n, jnp.int32))
-        if self._sync_timers:
-            # device-time attribution: without the block the stamp below
-            # measures dispatch only (JAX async dispatch)
-            jax.block_until_ready(pf.logits)
-        dt = time.perf_counter() - t_pf
-        if tr is not None:
-            tr.end("prefill_chunk", request_track(pf.req.uid))
-        pf.s += self.chunk
-        self._m_prefill_chunks.inc()
-        self._m_prefill_seconds.inc(dt)
-        self._m_prefill_chunk_hist.observe(dt)
-        # cache the boundary just crossed (whole-chunk prefixes only — a
-        # partial final chunk contains pad rows and is never cacheable)
-        if self.prefix_cache is not None and pf.s <= pf.n:
-            entry = self.prefix_cache.insert(
-                pf.req.prompt[:pf.s], state=pf.state, logits=pf.logits,
-                parent=pf.tip)
-            if entry is not None:
-                self.prefix_cache.acquire(entry)
-                if pf.tip is not None:  # the parent link keeps it alive now
-                    self.prefix_cache.release(pf.tip)
-                pf.tip = entry
+        with self._phase("prefill_chunk", request_track(pf.req.uid),
+                         s=pf.s):
+            blk = np.zeros((1, self.chunk), np.int32)
+            seg = pf.req.prompt[pf.s:pf.s + self.chunk]
+            blk[0, :len(seg)] = seg
+            fn = self.chunk_cache.get("chunk", self.chunk, 1, self.policy)
+            pf.state, pf.logits = fn(self.params, pf.state, jnp.asarray(blk),
+                                     jnp.asarray(pf.n, jnp.int32))
+            if self._sync_timers:
+                # device-time attribution: without the block the span measures
+                # dispatch only (JAX async dispatch)
+                jax.block_until_ready(pf.logits)
+            pf.s += self.chunk
+            self._m_prefill_chunks.inc()
+            # cache the boundary just crossed (whole-chunk prefixes only — a
+            # partial final chunk contains pad rows and is never cacheable)
+            if self.prefix_cache is not None and pf.s <= pf.n:
+                entry = self.prefix_cache.insert(
+                    pf.req.prompt[:pf.s], state=pf.state, logits=pf.logits,
+                    parent=pf.tip)
+                if entry is not None:
+                    self.prefix_cache.acquire(entry)
+                    if pf.tip is not None:  # the parent link keeps it alive now
+                        self.prefix_cache.release(pf.tip)
+                    pf.tip = entry
 
     def _admit(self, pf, sched, tok, live, active, remaining, last_emit, t0):
         r = pf.req
         tr = self.trace
         tid = request_track(r.uid)
         fn = self.chunk_cache.get("finalize", self.chunk, 1, self.policy)
-        seeds = _request_seeds([r])
-        if tr is not None:
-            tr.begin("finalize", tid)
-        cache = fn(self.params, self.lkv_params, pf.state,
-                   jnp.asarray(pf.n, jnp.int32), seeds)
+        with self._phase("finalize", tid):
+            cache = fn(self.params, self.lkv_params, pf.state,
+                       jnp.asarray(pf.n, jnp.int32), _request_seeds([r]))
+            if self._sync_timers:
+                jax.block_until_ready(cache)
         if self.prefix_cache is not None and pf.tip is not None:
             self.prefix_cache.release(pf.tip)
             pf.tip = None
-        if self.capture_admission:
-            r.admission_cache = {
-                key: np.asarray(val) for key, val in cache["attn"].items()
-                if key in ("mask", "pos", "score")
-            }
-        if self._sync_timers:
-            jax.block_until_ready(cache)
-        pf.logits.block_until_ready()
-        if tr is not None:
-            tr.end("finalize", tid)
+        with self._phase("admit_sync"):
+            if self.capture_admission:
+                r.admission_cache = {
+                    key: np.asarray(val) for key, val in cache["attn"].items()
+                    if key in ("mask", "pos", "score")
+                }
+            pf.logits.block_until_ready()
         if self.pool is not None:
-            slot = self._paged_place(sched, r, cache)
+            with self._phase("place"):
+                slot = self._paged_place(sched, r, cache)
             if slot is None:
                 # the gate's headroom was eaten by running slots' appends
                 # during this prefill: back to the queue head, re-prefill
@@ -1204,12 +1216,14 @@ class ContinuousEngine(_SlotDecodeMixin):
                     tr.end("request", tid, outcome="admission_blocked")
                 return tok, live
         else:
-            slot = sched.place(r)
-            live = self._insert_fn(live, cache, slot)
+            with self._phase("place"):
+                slot = sched.place(r)
+                live = self._insert_fn(live, cache, slot)
         now = time.perf_counter() - t0
         self._seeds_h[slot] = r.eviction_seed
-        first = self._first_token(pf.logits, r.eviction_seed, pf.n)
-        tok = tok.at[slot, 0].set(first)
+        with self._phase("first_token"):
+            first = self._first_token(pf.logits, r.eviction_seed, pf.n)
+            tok = tok.at[slot, 0].set(first)
         r.out_tokens = [first]
         if tr is not None:
             tr.instant("first_token", tid, token=first)
@@ -1317,8 +1331,9 @@ class ContinuousEngine(_SlotDecodeMixin):
         None when the pool cannot cover the kept rows right now."""
         mask = cache["attn"]["mask"]  # (L, 1, C, KV)
         C = mask.shape[2]
-        rows = jnp.arange(C, dtype=jnp.int32)[None, None, :, None]
-        used = int(jnp.max(jnp.where(mask, rows, 0))) + 1
+        with self._phase("admit_sync"):
+            rows = jnp.arange(C, dtype=jnp.int32)[None, None, :, None]
+            used = int(jnp.max(jnp.where(mask, rows, 0))) + 1
         ids = self._alloc_blocks(self.pool.blocks_for(used))
         if ids is None:
             return None
@@ -1341,7 +1356,8 @@ class ContinuousEngine(_SlotDecodeMixin):
         self._table_h[slot, :len(ids)] = ids
         self._table_dev = _snapshot(self._table_h)
         self._cursor_h[slot] = self.capacity  # appends start where dense do
-        self._npos_h[slot] = int(cache["next_pos"][0, 0])
+        with self._phase("admit_sync"):
+            self._npos_h[slot] = int(cache["next_pos"][0, 0])
         if self._score_dev is not None:
             # arm the slot's cumulative tallies exactly as the dense
             # engine's add_decode_eviction_scores seeds its score field
@@ -1453,36 +1469,34 @@ class ContinuousEngine(_SlotDecodeMixin):
                 self._slot_blocks[slot].append(int(ids[0]))
             if aborted:
                 continue
-            tr = self.trace
-            if tr is not None:
-                tr.begin("paged_sweep", request_track(sched.running[slot].uid))
-            self._table_dev = _snapshot(self._table_h)
-            ptree, self._score_dev = paged_sweep(
-                self.pool.tree(), self._score_dev, self._table_dev,
-                jnp.asarray(slot, jnp.int32), capacity=self.capacity,
-                depth=self._paged_depth, block_size=bs, nb_keep=nb_keep)
-            self.pool.set_tree(ptree)
-            # the compacted tail is dead weight now: free it (the whole
-            # point — blocks return to the pool mid-generation) and
-            # re-promise the same count for the next growth window
-            freed = [int(self._table_h[slot, jb]) for jb in
-                     range(nb_keep, nb)]
-            self.pool.free_run(freed)
-            fs = set(freed)
-            self._slot_blocks[slot] = [
-                b for b in self._slot_blocks[slot] if b not in fs]
-            self._table_h[slot, nb_keep:nb] = 0
-            if self.reserve_appends:
-                ok = self.pool.reserve(len(freed))
-                assert ok  # the freed blocks are on the free list
-                self._slot_reserved[slot] += len(freed)
-            self._cursor_h[slot] = self.capacity
-            self._table_dev = _snapshot(self._table_h)
-            if self._sync_timers:
-                jax.block_until_ready(self._score_dev)
-            if tr is not None:
-                tr.end("paged_sweep", request_track(sched.running[slot].uid),
-                       blocks_freed=len(freed))
+            with self._phase("paged_sweep",
+                             request_track(sched.running[slot].uid)) as end:
+                self._table_dev = _snapshot(self._table_h)
+                ptree, self._score_dev = paged_sweep(
+                    self.pool.tree(), self._score_dev, self._table_dev,
+                    jnp.asarray(slot, jnp.int32), capacity=self.capacity,
+                    depth=self._paged_depth, block_size=bs, nb_keep=nb_keep)
+                self.pool.set_tree(ptree)
+                # the compacted tail is dead weight now: free it (the
+                # whole point — blocks return to the pool mid-generation)
+                # and re-promise the same count for the next growth window
+                freed = [int(self._table_h[slot, jb]) for jb in
+                         range(nb_keep, nb)]
+                self.pool.free_run(freed)
+                fs = set(freed)
+                self._slot_blocks[slot] = [
+                    b for b in self._slot_blocks[slot] if b not in fs]
+                self._table_h[slot, nb_keep:nb] = 0
+                if self.reserve_appends:
+                    ok = self.pool.reserve(len(freed))
+                    assert ok  # the freed blocks are on the free list
+                    self._slot_reserved[slot] += len(freed)
+                self._cursor_h[slot] = self.capacity
+                self._table_dev = _snapshot(self._table_h)
+                if self._sync_timers:
+                    jax.block_until_ready(self._score_dev)
+                if end is not None:
+                    end["blocks_freed"] = len(freed)
             self._m_sweeps.inc()
 
     def _on_retire(self, slot: int, req: Request) -> None:

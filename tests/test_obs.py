@@ -199,15 +199,57 @@ def _walk(node):
         yield from _walk(c)
 
 
-def test_span_invariants_over_served_trace(model):
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter and
+    exit as a B / E event on one track."""
+
+    def __init__(self):
+        self.events: list[dict] = []
+
+    def __call__(self, name, **args):
+        log = self.events
+
+        class _Ann:
+            def __enter__(self):
+                log.append({"name": name, "ph": "B", "ts": len(log),
+                            "tid": "thread", "args": args})
+
+            def __exit__(self, *exc):
+                log.append({"name": name, "ph": "E", "ts": len(log),
+                            "tid": "thread", "args": {}})
+
+        return _Ann()
+
+
+# the serving loop's host phases (engine track) every paged run passes
+LOOP_PHASES = {"schedule", "admit", "admit_sync", "place", "first_token",
+               "append_blocks", "decode_chunk", "token_sync", "collect",
+               "clock"}
+# long-lived spans: opened and closed in different calls, recorder-only
+RECORDER_ONLY = ("request", "decode")
+
+
+def test_span_invariants_over_served_trace(model, monkeypatch):
     cfg, params, lkv = model
     chunk = 64
     reqs = make_trace_requests(cfg, chunk=chunk, seed=0, n_requests=5,
                                max_new=3)
     rec = TraceRecorder()
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
     got, eng = run_trace(cfg, params, lkv, policy="lookaheadkv",
                          requests=reqs, chunk=chunk, trace=rec)
     summary = validate_trace(rec)  # raises on nesting/closure/monotone
+    # every span but the long-lived ones is also a profiler annotation,
+    # entered and left in the order the recorder opens and closes it, so
+    # the annotations nest on the engine thread as the spans do
+    validate_trace(ann.events)
+    spans = [e for e in rec.events
+             if e["ph"] in "BE" and e["name"] not in RECORDER_ONLY]
+    assert [(e["ph"], e["name"]) for e in ann.events] == \
+        [(e["ph"], e["name"]) for e in spans]
+    assert all(a["args"] == e["args"] for a, e in zip(ann.events, spans)
+               if a["ph"] == "B")
     assert summary["tracks"] == len(reqs) + 1  # engine + one per request
     seqs = []
     for uid, r in got.items():
@@ -231,6 +273,8 @@ def test_span_invariants_over_served_trace(model):
     eng_names = {e["name"] for e in rec.events if e["tid"] == rec.ENGINE}
     assert "decode_chunk" in eng_names
     assert "jit_compile" in eng_names
+    assert LOOP_PHASES - {"append_blocks"} <= eng_names, \
+        "the dense engine runs every loop phase but the pool's"
     # the trace was captured with device-synced timers (the default when
     # tracing), and the chrome export records that
     assert rec.sync and rec.chrome_trace()["otherData"]["sync_timers"]
@@ -241,6 +285,72 @@ def test_span_invariants_over_served_trace(model):
         assert row["prefill_ms"] > 0
         assert row["first_token_ms"] is not None
         assert row["decode_ms"] > 0
+
+
+def _engine_thread(log_dir, names) -> list:
+    """(start ns, end ns, name) of the events named in ``names`` on the
+    host line that holds most of them, in the newest profile."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    f = sorted(Path(log_dir).rglob("*.xplane.pb"),
+               key=lambda p: p.stat().st_mtime)[-1]
+    best: list = []
+    for plane in ProfileData.from_file(str(f)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            evs = [(e.start_ns, e.end_ns, e.name) for e in line.events
+                   if e.name in names]
+            best = max(best, evs, key=len)
+    return sorted(best, key=lambda e: (e[0], -e[1]))
+
+
+def test_engine_phases_on_the_profiler_clock(model, tmp_path):
+    """Under ``jax.profiler.trace`` a recorder-attached engine's phases are
+    annotations on the engine thread of the host plane: one per recorder
+    span, nested as the spans are, and the loop's phases cover the serving
+    loop; with no recorder attached, none appear."""
+    cfg, params, lkv = model
+    reqs = make_trace_requests(cfg, chunk=64, seed=2, n_requests=4,
+                               max_new=4)
+    for i, r in enumerate(reqs):  # the last arrives after the others end
+        r.arrival_s = 0.0 if i < 3 else 1.5
+    pool = KVBlockPool(cfg, block_size=16, num_blocks=64)
+    _, eng = run_trace(cfg, params, lkv, policy="lookaheadkv",
+                       requests=reqs, chunk=64, kv_pool=pool)  # compiles
+    rec = TraceRecorder(sync=False)
+    eng.set_trace(rec)
+    with jax.profiler.trace(str(tmp_path / "on")):
+        eng.run([r.clone() for r in reqs])
+    spans = [e for e in rec.events
+             if e["ph"] == "B" and e["name"] not in RECORDER_ONLY]
+    names = {e["name"] for e in spans}
+    assert LOOP_PHASES | {"prefill_chunk", "finalize", "wait_arrival"} \
+        <= names
+    got = _engine_thread(tmp_path / "on", names)
+    assert sorted(e[2] for e in got) == sorted(e["name"] for e in spans)
+    stack: list = []
+    for s, e, name in got:  # properly nested
+        while stack and stack[-1][1] <= s:
+            stack.pop()
+        assert not stack or e <= stack[-1][1], (name, stack[-1])
+        stack.append((s, e, name))
+    # the loop's phases (engine track) leave little of the loop unnamed
+    loop = [(s, e) for s, e, n in got
+            if n in LOOP_PHASES | {"wait_arrival"} and n != "clock"]
+    top, end = [], -1
+    for s, e in loop:
+        if s >= end:
+            top.append((s, e))
+            end = e
+    covered = sum(e - s for s, e in top)
+    assert covered >= 0.9 * (top[-1][1] - top[0][0])
+    eng.set_trace(None)
+    with jax.profiler.trace(str(tmp_path / "off")):
+        eng.run([r.clone() for r in reqs[:2]])
+    assert _engine_thread(tmp_path / "off", names | {"request"}) == []
 
 
 def test_preempted_request_carries_replay_linkage(model):

@@ -16,6 +16,7 @@ this file loads the TPU compiler.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +39,7 @@ POOL_BLOCK_SIZES = (64, 16)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -50,8 +51,13 @@ def one_chip():
     # cache but never read back without a chip: keep the cache out of it
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
 
 
 def _compile(fn, sharding, *shapes):
@@ -124,3 +130,91 @@ def test_flash_and_dense_decode_compile(one_chip):
     _compile(lambda q, k, v, m: decode_attention_pallas(q, k, v, kv_mask=m),
              one_chip, ((4, H, HD), BF16), ((4, 3000, KV, HD), BF16),
              ((4, 3000, KV, HD), BF16), ((4, 3000), jnp.bool_))
+
+
+def _named_cases():
+    """name -> (function, argument shapes) of each Pallas kernel, small."""
+    C, K, n_obs, n_prompt = 256, 1024, 32, 1000
+    B, N, bs, nb = 2, 64, 64, 4
+    chunk = ((1, C, H, HD), BF16), ((1, K, KV, HD), BF16), \
+        ((1, K, KV, HD), BF16), ((), jnp.int32), ((), jnp.int32)
+    pool = ((B, H, HD), BF16), ((N, KV, bs, HD), BF16), \
+        ((N, KV, bs, HD), BF16), ((N, KV, bs), jnp.bool_), \
+        ((B, nb), jnp.int32)
+    return {
+        "chunk_attention": (
+            lambda q, k, v, off, nt: chunk_attention_pallas(
+                q, k, v, off, block_k=512), chunk),
+        "chunk_attention_masses": (
+            lambda q, k, v, off, nt: chunk_attention_masses_pallas(
+                q, k, v, off, nt, block_k=512), chunk),
+        "lookahead_score": (
+            lambda q, k, off, rv: lookahead_score_pallas(
+                q, k, n_prompt, q_offset=off, row_valid=rv, block_k=512),
+            (((1, n_obs, H, HD), BF16), ((1, n_prompt + n_obs, KV, HD), BF16),
+             ((), jnp.int32), ((1, n_obs), jnp.bool_))),
+        "flash_attention": (
+            lambda q, k, v: flash_attention_pallas(q, k, v),
+            (((1, 512, H, HD), BF16), ((1, 512, KV, HD), BF16),
+             ((1, 512, KV, HD), BF16))),
+        "decode_attention": (
+            lambda q, k, v, m: decode_attention_pallas(q, k, v, kv_mask=m),
+            (((B, H, HD), BF16), ((B, K, KV, HD), BF16),
+             ((B, K, KV, HD), BF16), ((B, K), jnp.bool_))),
+        "paged_attention": (paged_decode_attention_pallas, pool),
+        "paged_attention_masses": (paged_decode_masses_pallas, pool),
+    }
+
+
+@pytest.mark.parametrize("name", list(_named_cases()))
+def test_kernel_carries_its_name(one_chip, name):
+    """Each kernel's custom call is named after the kernel, the name by
+    which a device trace's op events tell the kernels apart."""
+    fn, shapes = _named_cases()[name]
+    text = _compile(fn, one_chip, *shapes).as_text()
+    calls = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert calls and all(re.sub(r"\.\d+$", "", c) == name for c in calls), \
+        calls
+
+
+def test_kv_copies_carry_their_scope(one_chip):
+    """The head-major re-layout before a kernel runs under the
+    ``kv_head_major`` scope, which the ops' metadata keeps."""
+    fn, shapes = _named_cases()["chunk_attention"]
+    text = _compile(fn, one_chip, *shapes).as_text()
+    assert re.search(r'op_name="[^"]*/kv_head_major/', text)
+
+
+def test_kernel_keeps_its_name_under_shard_map(topology, monkeypatch):
+    """Scored as the four-chip cell scores, per model shard under
+    ``shard_map`` over a described v5e:2x2 model=4 mesh (7B widths: 28 q
+    heads, 4 kv heads), the kernel's custom call still carries the
+    kernel's name."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels import ops
+    from repro.models.attention import sharded_lookahead_score
+
+    # this process's backend is the CPU: take the chip's kernel path
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_pallas_interpret", lambda: False)
+    mesh = Mesh(np.asarray(topology.devices).reshape(1, 4),
+                ("data", "model"))
+    n_obs, n_prompt, h, kv = 32, 1000, 28, 4
+
+    def f(q, k, off):
+        return sharded_lookahead_score(q, k, n_prompt, q_offset=off,
+                                       window=None, smesh=mesh)
+
+    sh = NamedSharding(mesh, P())
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in (
+        ((1, n_obs, h, HD), BF16), ((1, n_prompt + n_obs, kv, HD), BF16),
+        ((), jnp.int32))]
+    text = jax.jit(f).lower(*args).compile().as_text()
+    calls = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert calls and all(re.sub(r"\.\d+$", "", c) == "lookahead_score"
+                         for c in calls), calls
