@@ -2,14 +2,21 @@
 
 ``param_specs(cfg, mesh)`` mirrors the parameter tree with PartitionSpecs:
 
-* attention q/o projections shard the head dim on "model" when the head
-  count divides the axis (GQA: k/v shard only when kv heads divide, else
-  stay replicated — the standard Megatron GQA compromise);
-* MLP shards d_ff column→row (no resharding between the two matmuls);
+* attention q/k/v projections shard their head columns on "model" when
+  the head count divides the axis (GQA: k/v shard only when kv heads
+  divide, else stay replicated — the standard Megatron GQA compromise);
+* the attention ``wo`` and the dense MLP ``w_down`` are stored in the
+  split the manual-TP ``shard_map`` forward reads them in (output columns
+  on "model", ``column_split``) wherever that forward runs sharded, so no
+  weight moves between chips at run time; elsewhere ``wo`` splits its
+  head rows and ``w_down`` its d_ff rows, the Megatron row-parallel
+  layout the GSPMD forward partitions;
+* MLP gate/up shard d_ff columns;
 * MoE experts shard the expert dim on "model" (expert parallelism);
 * embeddings shard vocab when divisible, else d_model, else replicate;
 * ``cfg.fsdp`` additionally shards the d_model dim of big weights over
-  "data" (ZeRO-3-ish storage; XLA all-gathers at use) — beyond-paper;
+  "data" (the input rows of a column-split ``wo`` / ``w_down``;
+  ZeRO-3-ish storage; XLA all-gathers at use) — beyond-paper;
 * Mamba-2 / LoRA / norms / scalars replicate (see DESIGN.md §4 — the SSM
   inner projection is deliberately replicated in the baseline; §Perf
   revisits it).
@@ -34,6 +41,26 @@ def _div(n: int, size: int) -> bool:
     return size > 0 and n % size == 0
 
 
+def column_split(*, stacked: bool = False, rows=None) -> P:
+    """The manual-TP split of an ``(in, out)`` weight: output columns on
+    "model".  The one definition of that layout — ``param_specs`` stores
+    the column-parallel weights in it and the ``shard_map`` wrappers of
+    ``models/attention.py`` and ``models/mlp.py`` declare it as their
+    ``in_specs``, so stored and read layouts cannot drift apart.
+    ``stacked`` adds the leading layer axis; ``rows`` optionally shards
+    the input rows too (fsdp storage over "data", gathered at use)."""
+    return P(*((None,) if stacked else ()), rows, "model")
+
+
+def manual_tp(a, msize: int) -> bool:
+    """Whether attention runs hand-partitioned per model shard: kv heads
+    divide the axis (q heads then divide too, since ``H = G · KV``).
+    ``attention.model_shard_mesh`` applies this test to the serving
+    mesh."""
+    return (a is not None and msize > 1 and _div(a.num_kv_heads, msize)
+            and _div(a.num_heads, msize))
+
+
 def param_specs(cfg: ModelConfig, mesh, *, embed_replicated: bool = False) -> Any:
     """PartitionSpec tree mirroring ``init_params(cfg)`` output.
 
@@ -48,6 +75,12 @@ def param_specs(cfg: ModelConfig, mesh, *, embed_replicated: bool = False) -> An
     shard_q = a is not None and _div(a.num_heads, msize)
     shard_kv = a is not None and _div(a.num_kv_heads, msize)
     shard_ff = _div(cfg.d_ff, msize)
+    # where the manual-TP forward runs sharded it reads wo / w_down by
+    # output columns: ``attention.sharded_wo_linear`` when d_model divides
+    # the axis, ``mlp.apply_sharded`` when d_ff and d_model both do
+    tp = manual_tp(a, msize)
+    cols_wo = tp and _div(cfg.d_model, msize)
+    cols_down = tp and shard_ff and _div(cfg.d_model, msize)
     shard_exp = cfg.moe is not None and _div(cfg.moe.num_experts, msize)
     shard_shared = (cfg.moe is not None and
                     _div(cfg.moe.num_shared_experts * cfg.moe.d_expert, msize))
@@ -68,6 +101,17 @@ def param_specs(cfg: ModelConfig, mesh, *, embed_replicated: bool = False) -> An
         stacked = "layers" in parts  # leading L axis
         pre = (None,) if stacked else ()
 
+        def tp_read(block: str) -> bool:
+            # a decoder-stack block the manual-TP wrappers read; the
+            # whisper encoder and cross attention run through GSPMD
+            return "encoder" not in parts and parts[-2:-1] == [block]
+
+        def columns(on: bool) -> P:
+            if not on:
+                return P(*pre, fsdp, None)
+            rows = fsdp if _div(leaf.shape[-2], dsize) else None
+            return column_split(stacked=stacked, rows=rows)
+
         if name == "embed":
             return embed_spec
         if name == "lm_head":
@@ -76,10 +120,12 @@ def param_specs(cfg: ModelConfig, mesh, *, embed_replicated: bool = False) -> An
             return P(None, None)
         # --- attention (incl. whisper cross/encoder) ---
         if name == "wq":
-            return P(*pre, fsdp, "model" if shard_q else None)
+            return columns(shard_q)
         if name in ("wk", "wv"):
-            return P(*pre, fsdp, "model" if shard_kv else None)
+            return columns(shard_kv)
         if name == "wo":
+            if cols_wo and tp_read("attn"):
+                return columns(True)
             return P(*pre, "model" if shard_q else None, fsdp)
         if name == "bq":
             return P(*pre, "model" if shard_q else None)
@@ -100,8 +146,10 @@ def param_specs(cfg: ModelConfig, mesh, *, embed_replicated: bool = False) -> An
                 return P(*pre, "model" if shard_shared else None, fsdp)
         # --- dense MLP ---
         if name in ("w_gate", "w_up"):
-            return P(*pre, fsdp, "model" if shard_ff else None)
+            return columns(shard_ff)
         if name == "w_down":
+            if cols_down and tp_read("mlp"):
+                return columns(True)
             return P(*pre, "model" if shard_ff else None, fsdp)
         # --- Mamba-2: replicated in the baseline (DESIGN.md §4) ---
         if name in ("in_proj", "out_proj", "conv_w", "conv_b", "A_log",

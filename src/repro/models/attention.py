@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.config import AttentionConfig, ModelConfig
+from repro.common.sharding import column_split, manual_tp
 from repro.kernels import ops
 from repro.models import rope
 from repro.models.layers import dense_init, linear
@@ -218,7 +219,9 @@ def layer_window(a: AttentionConfig, is_global) -> "int | jnp.ndarray | None":
 # the unsharded call — so per-head outputs, the fused score-mass partials,
 # and the eviction kept-sets derived from them are bit-identical to
 # single-device serving, with no collective inside the attention block
-# (shards combine downstream, in the row-sharded ``wo`` matmul).  Pallas
+# (shards combine downstream: ``sharded_wo_linear`` all-gathers the head
+# slices and contracts them against ``wo``'s local output columns, the
+# split ``param_specs`` stores it in).  Pallas
 # kernels have no GSPMD partition rule, so the forced-Pallas dispatch
 # *requires* these shard_map wrappers to stay on the kernel path.
 
@@ -231,10 +234,11 @@ def _shard_map(f, mesh, in_specs, out_specs):
 def pin_activations(h, mesh):
     """Pin a scan-carried activation to batch-only sharding (feature dim
     unsharded).  Left to itself, GSPMD may feature-shard the carry between
-    layers to suit the row-sharded ``wo``/``w_down`` matmuls — turning
-    every ``rms_norm`` mean into a psum of per-shard partials whose
-    different summation order perturbs activations by bf16 ulps, and with
-    them the eviction scores sharded serving promises to keep bit-exact."""
+    layers to match the column-split outputs of the ``wo``/``w_down``
+    matmuls — turning every ``rms_norm`` mean into a psum of per-shard
+    partials whose different summation order perturbs activations by bf16
+    ulps, and with them the eviction scores sharded serving promises to
+    keep bit-exact."""
     if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
         return h
     if int(mesh.shape["model"]) == 1:
@@ -286,9 +290,9 @@ def _sharded_qkv_project(p, h, lm, lora, lora_scale, smesh):
           if p.get("b" + n[1:]) is not None}
     lo = {n: lora[n] for n in names
           if lora is not None and lora.get(n) is not None}
-    w_specs = {n: P(None, "model") for n in ws}
+    w_specs = {n: column_split() for n in ws}
     b_specs = {n: P("model") for n in bs}
-    lo_specs = {n: {"a": P(None, None), "b": P(None, "model")} for n in lo}
+    lo_specs = {n: {"a": P(None, None), "b": column_split()} for n in lo}
     bspec = _batch_spec(smesh, h.shape[0])
     have_lm = lm is not None
 
@@ -361,10 +365,10 @@ def sharded_wo_linear(out_flat, w, smesh, *, lora=None, lm=None, ls=1.0):
         return linear(of, wl, lora=lo, lora_mask=lml, lora_scale=ls)
 
     arrs = [out_flat, w]
-    specs = [P(bspec, None, "model"), P(None, "model")]
+    specs = [P(bspec, None, "model"), column_split()]
     if have_lora:
         arrs += [lora, lm]
-        specs += [{"a": P(None, None), "b": P(None, "model")},
+        specs += [{"a": P(None, None), "b": column_split()},
                   P(bspec, None, None)]
     return _shard_map(local, smesh, tuple(specs),
                       P(bspec, None, "model"))(*arrs)
@@ -373,16 +377,14 @@ def sharded_wo_linear(out_flat, w, smesh, *, lora=None, lm=None, ls=1.0):
 def model_shard_mesh(mesh, a: AttentionConfig):
     """The mesh when per-shard head dispatch applies, else None.
 
-    kv heads must divide the "model" axis (q heads then divide too, since
-    ``H = G · KV``); anything else degrades to the unsharded call — the
-    same replication fallback ``param_specs`` uses for the projections.
+    kv heads must divide the "model" axis (``sharding.manual_tp``);
+    anything else degrades to the unsharded call — the same test
+    ``param_specs`` makes before it stores weights in the split these
+    wrappers read.
     """
     if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
         return None
-    msize = int(mesh.shape["model"])
-    if msize == 1 or a.num_kv_heads % msize or a.num_heads % msize:
-        return None
-    return mesh
+    return mesh if manual_tp(a, int(mesh.shape["model"])) else None
 
 
 def _batch_spec(mesh, B: int):

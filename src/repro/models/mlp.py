@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.config import ModelConfig
+from repro.common.sharding import column_split
 from repro.models.layers import activation, dense_init, linear
 
 
@@ -105,10 +106,10 @@ def apply_sharded(
                       lora_scale=lora_scale)
 
     arrs = [h, {n: p[n] for n in names}]
-    specs = [P(bspec, None, None), {n: P(None, "model") for n in names}]
+    specs = [P(bspec, None, None), {n: column_split() for n in names}]
     if have_lora:
         arrs += [lsub, lora_mask]
-        specs += [{n: {"a": P(None, None), "b": P(None, "model")}
+        specs += [{n: {"a": P(None, None), "b": column_split()}
                    for n in lsub},
                   P(bspec, None, None)]
     return attn_mod._shard_map(local, smesh, tuple(specs),

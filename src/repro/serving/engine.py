@@ -551,7 +551,8 @@ class ContinuousEngine(_SlotDecodeMixin):
         # called per retired request in ``_collect``
         self.harvest = config.harvest
         # tensor-parallel serving: commit the params to their param_specs
-        # shardings (Megatron GQA rules — q/o on heads, k/v on kv heads
+        # shardings (Megatron GQA rules — q on heads, k/v on kv heads,
+        # wo and the MLP on the output columns the manual-TP forward reads,
         # over "model") so every jitted program below lowers sharded, and
         # thread the mesh into the chunk / finalize / decode bodies, where
         # attention.py shard_maps the kernels over each shard's local head

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from conftest import sweep_cases
+from repro.configs import ARCH_IDS
 from repro.models import rope
 
 
@@ -118,3 +119,79 @@ def test_cache_specs_cover_cache_tree():
                                                           if cap else 0))
         specs = sh.cache_specs(cfg, FakeMesh(), 128, cap)
         jax.tree.map(lambda s, sp: None, cache, specs)  # structure matches
+
+
+def _arch_specs(arch, msize):
+    from jax.sharding import PartitionSpec as P
+
+    from repro.common import sharding as sh
+    from repro.configs import get_config
+    from repro.models import transformer as tf
+
+    class FakeMesh:
+        shape = {"data": 256 // msize, "model": msize}
+
+    cfg = get_config(arch)
+    shapes = jax.eval_shape(
+        lambda: tf.init_params(jax.random.PRNGKey(0), cfg))
+    specs = sh.param_specs(cfg, FakeMesh())
+    leaves = jax.tree_util.tree_flatten_with_path(shapes)[0]
+    spec_leaves = jax.tree_util.tree_flatten_with_path(
+        specs, is_leaf=lambda x: isinstance(x, P))[0]
+    out = {}
+    for (path, leaf), (_, spec) in zip(leaves, spec_leaves):
+        key = "/".join(str(getattr(p, "key", p)) for p in path)
+        out[key] = (leaf, spec)
+    return cfg, FakeMesh.shape, out
+
+
+@pytest.mark.parametrize("msize", [16, 4])
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_wo_w_down_stored_as_manual_tp_reads_them(arch, msize):
+    """``wo`` and the dense ``w_down`` of the decoder stack are stored
+    column-split (output columns on "model") exactly where the manual-TP
+    ``shard_map`` wrappers run sharded — kv heads divide the axis, and so
+    does d_model (and d_ff, for ``w_down``) — and keep the row-parallel
+    spec everywhere else (MoE leaves, the whisper encoder and cross
+    attention, indivisible meshes).  Every sharded dim still divides."""
+    from jax.sharding import PartitionSpec as P
+
+    cfg, sizes, leaves = _arch_specs(arch, msize)
+    a = cfg.attn
+    tp = (a is not None and a.num_kv_heads % msize == 0
+          and a.num_heads % msize == 0)
+    fsdp = ("data" if cfg.fsdp and cfg.d_model % sizes["data"] == 0
+            else None)
+
+    def on(n):
+        return "model" if n % msize == 0 else None
+
+    seen = set()
+    for key, (leaf, spec) in leaves.items():
+        for dim, ax in enumerate(spec):
+            if ax is not None:
+                assert leaf.shape[dim] % sizes[ax] == 0, (key, spec)
+        parts = key.split("/")
+        if parts[-1] not in ("wo", "w_down"):
+            continue
+        if "moe" in parts:  # expert parallelism: out of this rule's reach
+            assert spec[-1] == fsdp and "model" not in spec[2:], (key, spec)
+            continue
+        block = parts[1] if parts[0] == "layers" else None
+        if parts[-1] == "wo":
+            cols = block == "attn" and tp and cfg.d_model % msize == 0
+            rows_spec = P(None, on(a.num_heads), fsdp)
+        else:
+            cols = (block == "mlp" and tp and cfg.d_ff % msize == 0
+                    and cfg.d_model % msize == 0)
+            rows_spec = P(None, on(cfg.d_ff), fsdp)
+        if cols:
+            rows = fsdp if leaf.shape[1] % sizes["data"] == 0 else None
+            assert spec == P(None, rows, "model"), (key, spec)
+            seen.add(parts[-1])
+        else:
+            assert spec == rows_spec, (key, spec)
+    # at the four-chip serving mesh both weights of every dense arch whose
+    # heads, kv heads, d_model and d_ff divide it are column-split
+    if msize == 4 and tp and cfg.moe is None and cfg.d_ff % 4 == 0:
+        assert seen == {"wo", "w_down"}, (arch, seen)

@@ -218,3 +218,132 @@ def test_kernel_keeps_its_name_under_shard_map(topology, monkeypatch):
                        text)
     assert calls and all(re.sub(r"\.\d+$", "", c) == "lookahead_score"
                          for c in calls), calls
+
+
+def _tp_programs(mesh):
+    """The serving engine's three sharded programs (a prefill chunk, the
+    finalize observation pass, a paged decode chunk) for a small model
+    whose q heads, kv heads, d_model and d_ff all divide model=4, with
+    arguments laid out as the engine lays them out: weights by
+    ``param_specs``, the pool by ``pool_specs``, the rest replicated."""
+    import dataclasses
+
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.common import sharding as sh
+    from repro.common.config import EvictionConfig
+    from repro.configs import get_smoke_config
+    from repro.core import policies
+    from repro.core.lookahead import init_lookahead_params
+    from repro.models import transformer as tf
+
+    base = get_smoke_config("smollm-135m")
+    cfg = dataclasses.replace(
+        base, d_model=512, d_ff=1536,
+        attn=dataclasses.replace(base.attn, num_heads=8, num_kv_heads=4,
+                                 head_dim=HD))
+    chunk, cap, bs, slots, depth = 128, 512, 64, 4, 256
+
+    def init():
+        return tf.init_params(jax.random.PRNGKey(0), cfg)
+
+    def replicated(shapes):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, P())),
+            shapes)
+
+    pshapes = jax.eval_shape(init)
+    params = sh.with_sharding(pshapes, sh.param_specs(cfg, mesh), mesh)
+    lkv = replicated(jax.eval_shape(lambda: init_lookahead_params(
+        jax.random.PRNGKey(1), cfg, init()["layers"])))
+    state = replicated(jax.eval_shape(
+        lambda: tf.init_chunk_state(cfg, "lookaheadkv", 1, cap)))
+    L, kv = cfg.num_layers, cfg.attn.num_kv_heads
+    pspec = sh.pool_specs(cfg, mesh)
+    pool = {n: jax.ShapeDtypeStruct(
+        (L, 32, kv, bs) + ((HD,) if n in ("k", "v") else ()),
+        {"k": BF16, "v": BF16, "pos": jnp.int32, "mask": jnp.bool_}[n],
+        sharding=NamedSharding(mesh, pspec[n])) for n in pspec}
+
+    def rep(shape, dtype=jnp.int32):
+        return replicated(jax.ShapeDtypeStruct(shape, dtype))
+
+    def chunk_fn(params, state, tokens, n_total):
+        return tf.prefill_chunk(params, cfg, state, tokens, n_total,
+                                policy="lookaheadkv", mesh=mesh)
+
+    def finalize_fn(params, lkv, state, n_total, seeds):
+        return tf.prefill_finalize(
+            params, cfg, state, n_total, policy="lookaheadkv",
+            evict=EvictionConfig(budget=128), lkv_params=lkv,
+            extra_slots=16, seeds=seeds, mesh=mesh)
+
+    def decode_fn(params, tok, table, cursor, next_pos, pool, active):
+        cache = {"attn": {"table": table}, "pool": pool, "cursor": cursor,
+                 "next_pos": next_pos}
+        last, cache, toks = policies.decode_chunk(
+            params, cfg, tok, cache, 2, active=active, paged_depth=depth,
+            mesh=mesh)
+        return last, cache["pool"], toks
+
+    programs = {
+        "chunk": (chunk_fn, (params, state, rep((1, chunk)), rep(()))),
+        "finalize": (finalize_fn,
+                     (params, lkv, state, rep(()), rep((1,)))),
+        "decode": (decode_fn,
+                   (params, rep((slots, 1)), rep((slots, depth // bs)),
+                    rep((slots,)), rep((slots, 1)), pool,
+                    rep((slots,), jnp.bool_))),
+    }
+    return cfg, pshapes, programs
+
+
+@pytest.mark.parametrize("program", ["chunk", "finalize", "decode"])
+def test_model4_programs_move_no_weights(topology, monkeypatch, program):
+    """Under model=4 on a described v5e:2x2, the sharded serving programs
+    read ``wo`` and ``w_down`` in the split ``param_specs`` stores them
+    in: the optimized HLO has no all-to-all (a stored/read mismatch
+    re-lays both weights out between the chips in every program), and
+    each stacked leaf's spec is the ``in_specs`` its ``shard_map``
+    declares for one layer's slice."""
+    import numpy as np
+    from jax.sharding import Mesh
+    from jax.sharding import PartitionSpec as P
+
+    from repro.common import sharding as sh
+    from repro.kernels import ops
+    from repro.models import attention as attn_mod
+
+    # this process's backend is the CPU: take the chip's kernel path
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_pallas_interpret", lambda: False)
+    declared: dict = {}
+    shard_map = attn_mod._shard_map
+
+    def recording(f, mesh, in_specs, out_specs):
+        mapped = shard_map(f, mesh, in_specs, out_specs)
+
+        def call(*args):
+            specs = jax.tree.leaves(in_specs,
+                                    is_leaf=lambda x: isinstance(x, P))
+            for a, spec in zip(jax.tree.leaves(args), specs, strict=True):
+                declared.setdefault(a.shape, set()).add(tuple(spec))
+            return mapped(*args)
+        return call
+
+    monkeypatch.setattr(attn_mod, "_shard_map", recording)
+    mesh = Mesh(np.asarray(topology.devices).reshape(1, 4),
+                ("data", "model"))
+    cfg, pshapes, programs = _tp_programs(mesh)
+    fn, args = programs[program]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"= \S+ all-to-all(-start)?\(", text)
+    stored = sh.param_specs(cfg, mesh)["layers"]
+    for block, name in (("attn", "wo"), ("mlp", "w_down")):
+        leaf = pshapes["layers"][block][name]
+        spec = stored[block][name]
+        assert declared.get(leaf.shape[1:]) == {tuple(spec)[1:]}, (
+            name, spec, declared.get(leaf.shape[1:]))
