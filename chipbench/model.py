@@ -1,12 +1,11 @@
-"""A configuration file (``chipbench/configs/<config>.json``) turned into
-the program's ``ModelConfig``, and its weights made on the device from the
-seed.
+"""A model's weights made on the device from the seed.
 
 The weights are the benchmark's own: the program is asked only for the
-layout of its parameter tree (``jax.eval_shape`` of its init), and every
-value is drawn here, in one jitted call, in the type it is served in.  The
-plain reference reads the same arrays, so neither side takes weights the
-other made.
+layout of its parameter tree (``jax.eval_shape`` of its init, for the
+``ModelConfig`` an architecture module builds), and every value is drawn
+here, in one jitted call, in the type it is served in.  The plain
+reference reads the same arrays, so neither side takes weights the other
+made.
 """
 
 from __future__ import annotations
@@ -16,39 +15,16 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def model_config(c: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.common.config import (AttentionConfig, LookaheadConfig,
-                                     ModelConfig)
-
-    heads = int(c["num_attention_heads"])
-    lk = c["lookahead"]
-    return ModelConfig(
-        name=c["name"], arch_type="dense",
-        num_layers=int(c["num_hidden_layers"]),
-        d_model=int(c["hidden_size"]), d_ff=int(c["intermediate_size"]),
-        vocab_size=int(c["vocab_size"]),
-        attn=AttentionConfig(
-            num_heads=heads, num_kv_heads=int(c["num_key_value_heads"]),
-            head_dim=int(c["hidden_size"]) // heads, qkv_bias=True,
-            rope_theta=float(c["rope_theta"])),
-        lookahead=LookaheadConfig(
-            n_lookahead=int(lk["n_lookahead"]), lora_rank=int(lk["lora_rank"]),
-            lora_alpha=float(lk["lora_alpha"]),
-            pool_kernel=int(lk["pool_kernel"]),
-            window_size=int(lk["window_size"])),
-        norm_eps=float(c["rms_norm_eps"]), act=c["hidden_act"],
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        dtype=c["torch_dtype"], source=c["source"])
-
-
 def _leaf_name(path) -> str:
     return str(getattr(path[-1], "key", path[-1]))
 
 
-def _draw(key, name: str, shape, dtype, *, lora_rank: int = 0):
-    """One leaf's values by the role its name gives it."""
+def _draw(key, name: str, shape, dtype, *, lora_rank: int = 0, rule=None):
+    """One leaf's values by the role its name gives it, or by the
+    architecture's own ``rule(name, z, shape)`` where that gives any."""
     z = jax.random.normal(key, shape, jnp.float32)
+    if rule is not None and (x := rule(name, z, shape)) is not None:
+        return x.astype(dtype)
     if name in ("embed", "emb"):
         x = z * 0.02
     elif name == "a":  # LoRA down-projection (rank r)
@@ -62,18 +38,21 @@ def _draw(key, name: str, shape, dtype, *, lora_rank: int = 0):
     return x.astype(dtype)
 
 
-def _fill(key, shapes, *, lora_rank: int = 0):
+def _fill(key, shapes, *, lora_rank: int = 0, rule=None):
     leaves, tree = jax.tree_util.tree_flatten_with_path(shapes)
     keys = jax.random.split(key, len(leaves))
     return jax.tree_util.tree_unflatten(tree, [
-        _draw(k, _leaf_name(p), s.shape, s.dtype, lora_rank=lora_rank)
+        _draw(k, _leaf_name(p), s.shape, s.dtype, lora_rank=lora_rank,
+              rule=rule)
         for k, (p, s) in zip(keys, leaves)])
 
 
-def make_weights(seed: int, cfg, mesh=None):
+def make_weights(seed: int, cfg, mesh=None, *, arch=None):
     """(params, lookahead params) on the device, drawn from ``seed``:
     bf16 model weights laid out as the program's init lays them out (and
-    sharded by its ``param_specs`` under a mesh), f32 lookahead modules."""
+    sharded by its ``param_specs`` under a mesh), f32 lookahead modules.
+    The architecture module ``arch`` may give a ``draw(name, z, shape)``
+    rule for leaves the roles here do not name (None: the role's draw)."""
     from repro.core.lookahead import init_lookahead_params
     from repro.models import transformer as tf
 
@@ -94,7 +73,8 @@ def make_weights(seed: int, cfg, mesh=None):
                                is_leaf=lambda x: isinstance(x, P)),
                   jax.tree.map(lambda _: NamedSharding(mesh, P()), lshapes))
     r = cfg.lookahead.lora_rank
-    make = jax.jit(lambda kp, kl: (_fill(kp, shapes),
+    rule = getattr(arch, "draw", None)
+    make = jax.jit(lambda kp, kl: (_fill(kp, shapes, rule=rule),
                                    _fill(kl, lshapes, lora_rank=r)),
                    out_shardings=out_sh)
     return make(kp, kl)

@@ -24,22 +24,6 @@ from chipbench import flops, phases, reduce, tracing
 SCOPES = ("kv_head_major", "kv_buffer_write", "lookahead_rows",
           "lookahead_scores", "topk_evict")
 KV_COPIES = ("kv_head_major", "kv_buffer_write")
-F32 = 4  # bytes
-
-
-def lookahead_score_call(w: flops.Widths, n_prompt: int, n_obs: int,
-                         chips: int = 1) -> tuple[float, float]:
-    """(operations, bytes) of one ``lookahead_score`` call (one layer, one
-    chip): QK^T of the ``n_obs`` lookahead rows of every head over the
-    ``n_prompt`` prompt keys; it must read q and K once and write the f32
-    scores.  The kernel's second pass over K (the masses, against the
-    final softmax statistics) is not counted: the algorithm needs one."""
-    h = w.heads // chips
-    kv = w.kv_heads // chips
-    ops = 2.0 * n_obs * h * w.head_dim * n_prompt
-    nbytes = (flops.BF16 * (n_obs * h + n_prompt * kv) * w.head_dim
-              + F32 * h * n_prompt)
-    return ops, nbytes
 
 
 def finalize_dispatches(rec) -> list:
@@ -82,8 +66,8 @@ def lookahead_score_roofline_pct(rec):
             if t <= 0 or i is None:
                 continue
             n = disp[i][1]
-            ops, nbytes = lookahead_score_call(w, n, n_obs, rec["chips"])
-            least += w.layers * flops.least_time_s(ops, nbytes, peak)[0]
+            least += flops.calls_least_s(
+                w.lookahead_score_call(n, n_obs, rec["chips"]), peak)
             spent += t / 1e9
     return 100.0 * least / spent if least > 0 and spent > 0 else None
 

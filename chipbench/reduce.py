@@ -4,7 +4,8 @@ A reader gets the run record ``rec`` that ``run.py`` builds in a traced
 run: the served requests, the engine's request spans on the engine clock
 (``obs.trace`` events, seconds), the reduced device trace
 (``tracing.Trace``) with the engine-clock span it covers, and the cell's
-widths, serving settings, chip count and device peaks.
+widths (its architecture module's ``widths``, which gives every count),
+serving settings, chip count and device peaks.
 """
 
 from __future__ import annotations
@@ -80,10 +81,10 @@ def model_flops(rec) -> float:
     the whole model (attention over their causal prefix, logits of each
     chunk's last row) and generated tokens (attention over their cache)."""
     w = rec["widths"]
-    total = sum(flops.prefill_chunk_flops(w, s, rows)
+    total = sum(w.prefill_chunk_flops(s, rows)
                 for s, rows in prefill_chunks(rec) if rows > 0)
     for _, steps, slots, keys in decode_chunks(rec):
-        total += steps * slots * flops.decode_token_flops(w, keys)
+        total += steps * slots * w.decode_token_flops(keys)
     return total
 
 
@@ -119,8 +120,8 @@ def chunk_attn_roofline_pct(rec):
     least = 0.0
     for s, rows in prefill_chunks(rec):
         if rows > 0:
-            ops, nbytes = flops.chunk_attn_call(w, s, rows, rec["chips"])
-            least += w.layers * flops.least_time_s(ops, nbytes, peak)[0]
+            least += flops.calls_least_s(
+                w.chunk_attn_call(s, rows, rec["chips"]), peak)
     return 100.0 * least / spent if least > 0 else None
 
 

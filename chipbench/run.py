@@ -47,7 +47,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from chipbench import cells, flops, loadgen, tracing  # noqa: E402
+from chipbench import cells, loadgen, tracing  # noqa: E402
 
 TRACE_START = 0.4  # traced stretch: from 40% of the window ...
 TRACE_SECONDS = 8.0  # ... for 8 s (or to the window's end)
@@ -214,7 +214,7 @@ def compare(cell, cfg, params, lkv, reqs, *, control: bool = False) -> dict:
 
     from chipbench import reference
 
-    spec = reference.spec_of(cfg, int(cell.config["serving"]["budget"]))
+    spec = cell.arch.spec_of(cfg, int(cell.config["serving"]["budget"]))
     m_pad = -(-int(cell.traffic["output_len"]["max"]) // 128) * 128
     gaps, cgaps = [], []
     for r in reqs:
@@ -226,10 +226,11 @@ def compare(cell, cfg, params, lkv, reqs, *, control: bool = False) -> dict:
         gen[:m] = r.out_tokens
         args = (params, lkv, jnp.asarray(prompt), jnp.asarray(n, jnp.int32),
                 jnp.asarray(gen))
-        logits, _ = reference.forward(*args, spec=spec)
+        logits, _ = reference.forward(*args, spec=spec, arch=cell.arch)
         gaps.append(np.asarray(reference.gaps(logits, jnp.asarray(gen)))[:m])
         if control:
-            low, _ = reference.forward(*args, spec=spec, quant=True)
+            low, _ = reference.forward(*args, spec=spec, arch=cell.arch,
+                                       quant=True)
             pick = jnp.argmax(low, -1).astype(jnp.int32)
             cgaps.append(np.asarray(reference.gaps(logits, pick))[:m])
             del low
@@ -277,13 +278,13 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool,
     if require_tpu:
         enable_cache()
     counter = CompileCounter()
-    cfg = model.model_config(cell.config)
+    cfg = cell.arch.model_config(cell.config)
     mesh = None
     if cell.chips > 1:
         from jax.sharding import Mesh
         mesh = Mesh(np.asarray(devs).reshape(1, cell.chips),
                     ("data", "model"))
-    params, lkv = model.make_weights(seed, cfg, mesh)
+    params, lkv = model.make_weights(seed, cfg, mesh, arch=cell.arch)
     jax.block_until_ready((params, lkv))
     mix = cell.traffic
     max_new = int(mix["output_len"]["max"])
@@ -350,7 +351,7 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool,
                "config": cell.config, "serving": {**cell.config["serving"],
                                                   **mix["serving"]},
                "chips": cell.chips, "peak": peaks.get(devs[0].device_kind),
-               "widths": flops.Widths.of(cell.config)}
+               "widths": cell.arch.widths(cell.config)}
         device["busy_s"] = tracing.busy_s(tr)
         device["window_s"] = tr.window_s
         result["breakdown"] = tracing.breakdown(tr)

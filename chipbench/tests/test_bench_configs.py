@@ -1,6 +1,6 @@
-"""Each configuration file builds the program's ModelConfig at the
-published widths of its source, and the program's parameter tree has the
-published parameter count."""
+"""Each configuration file builds, through its architecture module, the
+program's ModelConfig at the published widths of its source, and the
+program's parameter tree has the published parameter count."""
 
 import json
 from pathlib import Path
@@ -8,9 +8,10 @@ from pathlib import Path
 import jax
 import pytest
 
-from chipbench import model
+from chipbench import cells, model
 
-CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+ROOT = Path(__file__).resolve().parents[2]
+CONFIGS = ROOT / "chipbench" / "configs"
 
 # from the sources' config.json files (and the Qwen2 report's table)
 PUBLISHED = {
@@ -27,7 +28,9 @@ PUBLISHED = {
 def test_published_widths(name):
     c = json.loads((CONFIGS / f"{name}.json").read_text())
     assert c["name"] == name and c["reduced"] == []
-    cfg = model.model_config(c)
+    arch = cells.arch_module(ROOT, c.get("arch", "qwen2"))
+    assert arch.__file__.endswith("qwen2.py")
+    cfg = arch.model_config(c)
     want = PUBLISHED[name]
     a = cfg.attn
     assert (cfg.num_layers, cfg.d_model, cfg.d_ff, a.num_heads,
@@ -50,10 +53,11 @@ def test_published_widths(name):
 def test_weights_follow_the_seed():
     c = json.loads((Path(__file__).parent / "data" /
                     "tiny-qwen2.json").read_text())
-    cfg = model.model_config(c)
-    a, la = model.make_weights(2**31 + 7, cfg)
-    b, lb = model.make_weights(2**31 + 7, cfg)
-    d, _ = model.make_weights(7, cfg)
+    arch = cells.arch_module(ROOT, "qwen2")
+    cfg = arch.model_config(c)
+    a, la = model.make_weights(2**31 + 7, cfg, arch=arch)
+    b, lb = model.make_weights(2**31 + 7, cfg, arch=arch)
+    d, _ = model.make_weights(7, cfg, arch=arch)
     same = jax.tree.map(lambda x, y: bool((x == y).all()), (a, la), (b, lb))
     assert all(jax.tree.leaves(same))
     assert not bool((a["embed"] == d["embed"]).all())

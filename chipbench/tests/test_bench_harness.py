@@ -22,27 +22,34 @@ E2E = [{"name": n, "unit": u, "better": "lower", "bound": 0.25,
                     ("setup_s", "s"))]
 
 
-def new_cell_root(tmp_path: Path) -> Path:
+def new_cell_root(tmp_path: Path, config: str = "tiny-qwen2",
+                  limits: str = "tiny-limits.json",
+                  arch: str | None = None) -> Path:
     """A checkout whose BENCHMARK.json gains a configuration, a mix and a
-    cell as new files only; the per-layer readers are the real ones."""
+    cell ``<config>.tiny`` (and with ``arch`` an architecture module) as
+    new files only; the per-layer readers and the architecture modules
+    already there are the real ones."""
+    cell = f"{config}.tiny"
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    bench["configs"].append({"name": "tiny-qwen2", "source": "test",
-                             "file": "chipbench/configs/tiny-qwen2.json",
+    bench["configs"].append({"name": config, "source": "test",
+                             "file": f"chipbench/configs/{config}.json",
                              "reduced": [], "why": "test"})
-    bench["workloads"].append({"name": "tiny-qwen2.tiny",
-                               "config": "tiny-qwen2", "traffic": "tiny",
-                               "chips": 1, "why": "test"})
+    bench["workloads"].append({"name": cell, "config": config,
+                               "traffic": "tiny", "chips": 1, "why": "test"})
     for m in bench["per_layer"]:
         if m["name"] in ("queue_wait_p50_ms", "idle_share.ttft_p50"):
-            m["workloads"].append("tiny-qwen2.tiny")
+            m["workloads"].append(cell)
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     b = tmp_path / "chipbench"
-    for sub in ("configs", "traffic", "limits"):
+    for sub in ("configs", "traffic", "limits", "arch"):
         (b / sub).mkdir(parents=True)
-    shutil.copy(DATA / "tiny-qwen2.json", b / "configs" / "tiny-qwen2.json")
+    shutil.copy(DATA / f"{config}.json", b / "configs" / f"{config}.json")
     shutil.copy(DATA / "tiny-traffic.json", b / "traffic" / "tiny.json")
-    shutil.copy(DATA / "tiny-limits.json",
-                b / "limits" / "tiny-qwen2.tiny.json")
+    shutil.copy(DATA / limits, b / "limits" / f"{cell}.json")
+    for f in (ROOT / "chipbench" / "arch").glob("*.py"):
+        (b / "arch" / f.name).symlink_to(f)
+    if arch is not None:
+        shutil.copy(DATA / "arch" / f"{arch}.py", b / "arch" / f"{arch}.py")
     (b / "metrics").symlink_to(ROOT / "chipbench" / "metrics")
     return tmp_path
 
@@ -135,8 +142,8 @@ def test_float8_control_fails(tmp_path, seed):
 
     from chipbench import loadgen, model
 
-    cfg = model.model_config(cell.config)
-    params, lkv = model.make_weights(seed, cfg)
+    cfg = cell.arch.model_config(cell.config)
+    params, lkv = model.make_weights(seed, cfg, arch=cell.arch)
     jax.block_until_ready(params)
     eng = run.build_engine(cell, cfg, params, lkv, None, 24)
     specs = loadgen.generate(cell.traffic, seed=seed, seconds=3,

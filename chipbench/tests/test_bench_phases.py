@@ -11,10 +11,11 @@ import numpy as np
 import pytest
 
 from chipbench import cells, named, phases, tracing
-from chipbench.flops import Widths
 
 DATA = Path(__file__).resolve().parent / "data"
 ROOT = Path(__file__).resolve().parents[2]
+QWEN2 = cells.arch_module(ROOT, "qwen2")
+Widths = QWEN2.Widths
 MS = 1_000_000  # ns
 
 
@@ -157,10 +158,11 @@ def test_no_phases_no_reading():
 def test_lookahead_score_call_counts():
     w = Widths(layers=2, d=8, ff=16, heads=4, kv_heads=2, head_dim=4,
                vocab=32)
-    ops, nbytes = named.lookahead_score_call(w, n_prompt=100, n_obs=2)
+    (layers, ops, nbytes), = w.lookahead_score_call(n_prompt=100, n_obs=2)
+    assert layers == 2
     assert ops == 2 * 2 * 4 * 4 * 100
     assert nbytes == 2 * (2 * 4 + 100 * 2) * 4 + 4 * 4 * 100
-    ops2, _ = named.lookahead_score_call(w, 100, 2, chips=2)
+    (_, ops2, _), = w.lookahead_score_call(100, 2, chips=2)
     assert ops2 == ops / 2
 
 
@@ -190,7 +192,7 @@ def test_lookahead_score_roofline_on_a_synthetic_finalize(monkeypatch):
     monkeypatch.setattr(phases, "clock_offset_s", lambda rec, dev: 0.0)
     assert named.lookahead_score_roofline_pct(rec) is None
     monkeypatch.setattr(phases, "clock_offset_s", lambda rec, dev: 0.25)
-    ops, nbytes = named.lookahead_score_call(w, 300, 2)
+    (_, ops, nbytes), = w.lookahead_score_call(300, 2)
     least = 2 * max(ops / 1e9, nbytes / 1e9)
     assert named.lookahead_score_roofline_pct(rec) == pytest.approx(
         100 * least / 0.001)
@@ -274,7 +276,7 @@ def chip_slice():
     rec = {"trace": tracing.Trace([dev], window_s=d["window_s"]),
            "trace_span": (0.0, d["window_s"]), "spans": d["spans"],
            "peak": d["peak"], "chips": d["chips"], "config": d["config"],
-           "widths": Widths.of(d["config"]), "serving": d["serving"]}
+           "widths": QWEN2.widths(d["config"]), "serving": d["serving"]}
     return d, rec, dev, {dev.name: [o[4] for o in d["ops"]]}
 
 

@@ -7,10 +7,11 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from chipbench import reduce, tracing
-from chipbench.flops import Widths
+from chipbench import cells, reduce, tracing
 
 DATA = Path(__file__).resolve().parent / "data"
+Widths = cells.arch_module(Path(__file__).resolve().parents[2],
+                           "qwen2").Widths
 
 
 def synthetic():

@@ -37,6 +37,7 @@ def main() -> None:
     cell = cells.Cell(
         name="tiny-qwen2-tp4.tiny", chips=4,
         config=json.loads((DATA / "tiny-qwen2-tp4.json").read_text()),
+        arch=cells.arch_module(ROOT, "qwen2"),
         traffic=json.loads((DATA / "tiny-traffic.json").read_text()),
         limits=json.loads((DATA / "tiny-limits.json").read_text()),
         end_to_end=e2e, per_layer=[], root=ROOT)
