@@ -20,6 +20,22 @@ from typing import Any, Optional, Tuple
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary frequency scaling (arXiv:2309.00071), as the ``yarn``
+    rope type of Hugging Face transformers defines it: pairs rotating
+    fewer than ``beta_slow`` times over ``original_max_position`` positions
+    are divided by ``factor``, pairs rotating more than ``beta_fast`` times
+    are kept, a linear ramp joins the two (ends truncated to whole pairs),
+    and cos and sin are multiplied by ``attention_factor``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0  # 0 => 0.1 * ln(factor) + 1
+
+
+@dataclass(frozen=True)
 class AttentionConfig:
     """Multi-head (grouped-query) attention settings."""
 
@@ -27,7 +43,14 @@ class AttentionConfig:
     num_kv_heads: int
     head_dim: int
     qkv_bias: bool = False
+    # rotary of the full-attention (global) layers, and of every layer of
+    # a model with one kind of layer
     rope_theta: float = 10_000.0
+    rope_yarn: Optional[YarnConfig] = None
+    # rotary of the windowed (local) layers of a local:global pattern;
+    # 0 => ``rope_theta``
+    local_rope_theta: float = 0.0
+    local_rope_yarn: Optional[YarnConfig] = None
     # 0 => full attention.  >0 => sliding-window span (causal, local).
     sliding_window: int = 0
     # 0 => homogeneous layers.  n => every n-th layer (index % n == n-1) is a
@@ -46,6 +69,14 @@ class AttentionConfig:
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
 
+    def rotary(self, is_global: bool = True):
+        """(theta, yarn) of a layer of the given kind."""
+        patterned = self.global_every > 0 or len(self.global_layers) > 0
+        if patterned and not is_global:
+            return (self.local_rope_theta or self.rope_theta,
+                    self.local_rope_yarn)
+        return self.rope_theta, self.rope_yarn
+
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
@@ -59,10 +90,15 @@ class AttentionConfig:
 class MoEConfig:
     """Fine-grained mixture-of-experts FFN (DeepSeek-MoE / Phi-3.5-MoE)."""
 
-    num_experts: int
+    num_experts: int  # experts the router scores (its output width)
     top_k: int
     d_expert: int  # per-expert hidden width
     num_shared_experts: int = 0
+    # expert parallelism: this layer holds experts
+    # [first_expert, first_expert + experts_held) of the ``num_experts``
+    # (0 => all) and adds only their gated outputs
+    experts_held: int = 0
+    first_expert: int = 0
     router_noise: float = 0.0
     load_balance_coef: float = 0.01
 
@@ -74,6 +110,11 @@ class MoEConfig:
     # E/k x extra FLOPs).  "sparse": sort-based capacity dispatch (top-k
     # FLOPs only) — the §Perf beyond-paper optimization.
     dispatch: str = "dense"
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this layer stacks."""
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -203,7 +244,7 @@ class ModelConfig:
         if self.moe is not None:
             m = self.moe
             per_layer += d * m.num_experts  # router
-            per_layer += m.num_experts * 3 * d * m.d_expert
+            per_layer += m.held * 3 * d * m.d_expert
             per_layer += m.num_shared_experts * 3 * d * m.d_expert
         elif self.d_ff > 0:
             per_layer += 3 * d * self.d_ff
@@ -224,8 +265,11 @@ class ModelConfig:
         if self.moe is None:
             return self.num_params()
         d, L, m = self.d_model, self.num_layers, self.moe
-        routed_total = L * m.num_experts * 3 * d * m.d_expert
-        routed_active = L * m.top_k * 3 * d * m.d_expert
+        routed_total = L * m.held * 3 * d * m.d_expert
+        # a held share sees top_k * held / num_experts routed experts per
+        # token on average
+        routed_active = L * m.top_k * 3 * d * m.d_expert * m.held \
+            // m.num_experts
         return self.num_params() - routed_total + routed_active
 
     def to_json(self) -> str:

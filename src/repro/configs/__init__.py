@@ -39,6 +39,10 @@ _MODULES = {
     # paper's own subject models (reduced-scale stand-ins train end-to-end)
     "llama3-8b": "llama3_8b",
     "tiny-llama": "tiny_llama",
+    # sparse experts with sliding and full layers; ":EP8" is one chip's
+    # share of its 8-way expert-parallel deployment
+    "mellum2-12b": "mellum2_12b",
+    "mellum2-12b-ep8": "mellum2_12b:EP8",
 }
 
 
@@ -47,11 +51,15 @@ def _module(arch_id: str):
         raise KeyError(
             f"unknown arch '{arch_id}'; known: {sorted(_MODULES)}"
         )
-    return importlib.import_module(f"repro.configs.{_MODULES[arch_id]}")
+    name = _MODULES[arch_id].partition(":")[0]
+    return importlib.import_module(f"repro.configs.{name}")
 
 
 def get_config(arch_id: str) -> ModelConfig:
-    return _module(arch_id).CONFIG
+    """The module's ``CONFIG``, or the attribute after a ``:`` in its
+    registry entry."""
+    attr = _MODULES.get(arch_id, "").partition(":")[2] or "CONFIG"
+    return getattr(_module(arch_id), attr)
 
 
 def get_smoke_config(arch_id: str) -> ModelConfig:

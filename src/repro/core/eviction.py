@@ -9,6 +9,18 @@ Position-based policies (StreamingLLM sink+recent, random, full) are
 expressed as synthetic score vectors so that one TopK path serves all
 policies — this also makes the "budget is always respected" property test
 uniform across policies.
+
+Windowed layers (a sliding layer of a local:global model) follow the same
+rule as full ones, with the window applied where attention is computed:
+the layer keeps, per (layer, kv head), the top-``budget`` prompt keys of
+its pooled lookahead scores, those scores being the observation rows'
+softmax mass under the layer's window (``core/scoring.py``: keys a row's
+window does not show get exactly zero mass); decode then reads the kept
+rows only inside the window, by their original position
+(``new_pos - pos < window``).  At budget = window (Mellum2: 1,024) the
+top-``budget`` is drawn from the window's last ``window - 1`` prompt keys
+and the max-pool's ``pool // 2`` spill keys past its edge, so a sliding
+layer keeps all but a few of the keys its window shows.
 """
 
 from __future__ import annotations

@@ -16,6 +16,11 @@ Score post-processing (paper's standard eviction configuration):
 GQA mean-reduction over the query heads of each KV group, then 1-D max-pool
 (kernel 7, same padding) along the key axis.
 
+On a windowed layer every score is computed under the layer's window (a
+query at position i sees keys j with i - j < window), so keys outside
+every observation row's window score exactly zero; eviction then takes
+the top-``budget`` as on any layer (``core/eviction.py``).
+
 Streaming (chunked-prefill) scoring
 -----------------------------------
 ``ScoreState`` reformulates every single-pass policy's importance score as

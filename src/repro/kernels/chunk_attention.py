@@ -154,7 +154,9 @@ def chunk_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, C, hd), q.dtype),
         interpret=interpret,
-        name="chunk_attention",
+        # a windowed layer's calls carry a name of their own, so the trace
+        # tells the two kinds of layer apart
+        name="chunk_attention" if window is None else "chunk_attention_window",
     )(offs, head_major(q), head_major(k, Kp), head_major(v, Kp))
     return jnp.swapaxes(out, 1, 2)
 

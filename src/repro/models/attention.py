@@ -68,8 +68,10 @@ def qkv(
     lora: Optional[dict] = None,
     lora_scale: float = 1.0,
     rotary: bool = True,
+    is_global: jnp.ndarray | bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Project + rotate.  Returns q (B,S,H,hd), k/v (B,S,KV,hd)."""
+    """Project + rotate with the rotary of the layer's kind
+    (``AttentionConfig.rotary``).  Returns q (B,S,H,hd), k/v (B,S,KV,hd)."""
     B, S, _ = h.shape
     lm = inp.lookahead_mask
     smesh = model_shard_mesh(inp.mesh, a)
@@ -92,9 +94,21 @@ def qkv(
             q = rope.apply_mrope(q, inp.mrope_positions, a.rope_theta, a.mrope_sections)
             k = rope.apply_mrope(k, inp.mrope_positions, a.rope_theta, a.mrope_sections)
         else:
-            q = rope.apply_rope(q, inp.positions, a.rope_theta)
-            k = rope.apply_rope(k, inp.positions, a.rope_theta)
+            q = _rotate(a, q, inp.positions, is_global)
+            k = _rotate(a, k, inp.positions, is_global)
     return q, k, v
+
+
+def _rotate(a: AttentionConfig, x, positions, is_global):
+    """The rotary of the layer's kind; under the scan's traced flag, both
+    kinds' rotations and a select (exact) where the kinds differ."""
+    glob, loc = a.rotary(True), a.rotary(False)
+    if glob == loc:
+        return rope.apply_rope(x, positions, *glob)
+    if isinstance(is_global, bool):
+        return rope.apply_rope(x, positions, *(glob if is_global else loc))
+    return jnp.where(is_global, rope.apply_rope(x, positions, *glob),
+                     rope.apply_rope(x, positions, *loc))
 
 
 def prefill_attention(
@@ -113,11 +127,13 @@ def prefill_attention(
     """Full-sequence attention.  Returns (out, q, k, v).
 
     ``is_global`` may be a traced bool (scanned local/global flag): local
-    layers apply the sliding-window mask, global layers don't.  Both cases
-    share one kernel call by selecting the window value (huge = unbounded).
-    ``kv_mask`` excludes keys (bucket-padded prompt rows) from every query.
+    layers apply the sliding-window mask and the local rotary, global
+    layers neither.  Both cases share one kernel call by selecting the
+    window value (huge = unbounded).  ``kv_mask`` excludes keys
+    (bucket-padded prompt rows) from every query.
     """
-    q, k, v = qkv(p, a, h, inp, lora=lora, lora_scale=lora_scale, rotary=rotary)
+    q, k, v = qkv(p, a, h, inp, lora=lora, lora_scale=lora_scale,
+                  rotary=rotary, is_global=is_global)
     window = layer_window(a, is_global) if causal else None
     out = ops.flash_attention(q, k, v, causal=causal, window=window,
                               kv_mask=kv_mask)
@@ -159,26 +175,28 @@ def chunk_prefill_attention(
     ``jax.lax.dynamic_update_slice`` would otherwise silently clamp the
     start index and corrupt earlier chunks' keys.
     """
-    q, k, v = qkv(p, a, h, inp, lora=lora, lora_scale=lora_scale)
+    q, k, v = qkv(p, a, h, inp, lora=lora, lora_scale=lora_scale,
+                  is_global=is_global)
     with jax.named_scope("kv_buffer_write"):
         k_buf = jax.lax.dynamic_update_slice(
             k_buf, k.astype(k_buf.dtype), (0, q_offset, 0, 0))
         v_buf = jax.lax.dynamic_update_slice(
             v_buf, v.astype(v_buf.dtype), (0, q_offset, 0, 0))
-    window = layer_window(a, is_global)
-    masses = None
     smesh = model_shard_mesh(inp.mesh, a)
-    if smesh is not None:
-        out, masses = _sharded_chunk_attention(
-            q, k_buf, v_buf, q_offset=q_offset, window=window,
-            score_masses=score_masses, n_total=n_total, mesh=smesh)
-    elif score_masses:
-        out, masses = ops.chunk_attention(
-            q, k_buf, v_buf, q_offset=q_offset, window=window,
-            score_masses=True, n_total=n_total)
-    else:
-        out = ops.chunk_attention(q, k_buf, v_buf, q_offset=q_offset,
-                                  window=window)
+
+    def attend(window):  # -> (out, masses or None)
+        if smesh is not None:
+            return _sharded_chunk_attention(
+                q, k_buf, v_buf, q_offset=q_offset, window=window,
+                score_masses=score_masses, n_total=n_total, mesh=smesh)
+        if score_masses:
+            return ops.chunk_attention(
+                q, k_buf, v_buf, q_offset=q_offset, window=window,
+                score_masses=True, n_total=n_total)
+        return ops.chunk_attention(q, k_buf, v_buf, q_offset=q_offset,
+                                   window=window), None
+
+    out, masses = by_kind(a, is_global, attend)
     B, C = h.shape[:2]
     out = out.reshape(B, C, a.q_dim)
     out = sharded_wo_linear(out, p["wo"], smesh, lora=_lora_for(lora, "wo"),
@@ -189,6 +207,10 @@ def chunk_prefill_attention(
 _HUGE_WINDOW = 1 << 30
 
 
+def _patterned(a: AttentionConfig) -> bool:
+    return a.global_every > 0 or len(a.global_layers) > 0
+
+
 def layer_window(a: AttentionConfig, is_global) -> "int | jnp.ndarray | None":
     """Resolve the attention window for one layer.
 
@@ -196,8 +218,7 @@ def layer_window(a: AttentionConfig, is_global) -> "int | jnp.ndarray | None":
     traced int32 scalar (scanned local/global pattern: global layers get a
     window larger than any sequence, which the masks treat as unbounded).
     """
-    patterned = a.global_every > 0 or len(a.global_layers) > 0
-    if patterned:
+    if _patterned(a):
         if isinstance(is_global, bool):
             return None if is_global else a.sliding_window
         return jnp.where(
@@ -208,6 +229,20 @@ def layer_window(a: AttentionConfig, is_global) -> "int | jnp.ndarray | None":
     if a.sliding_window > 0:
         return a.sliding_window
     return None
+
+
+def by_kind(a: AttentionConfig, is_global, fn):
+    """``fn(window)`` with the layer's window as a static value, which the
+    Pallas kernels need.  Under the layer scan of a local:global pattern
+    the layer's kind is a traced flag: a ``lax.cond`` then picks between
+    the full layers' call (``window=None``) and the windowed layers'
+    (``window=sliding_window``), so both kinds run the kernels.  The
+    cond wraps only the kernel call: the layer's weights stay in the
+    scan, where their slices fuse into the matmuls."""
+    if isinstance(is_global, bool) or not _patterned(a):
+        return fn(layer_window(a, is_global))
+    return jax.lax.cond(is_global, lambda: fn(None),
+                        lambda: fn(a.sliding_window))
 
 
 # -- tensor-parallel kernel dispatch ----------------------------------------
@@ -403,24 +438,19 @@ def _sharded_chunk_attention(q, k_buf, v_buf, *, q_offset, window,
 
     bspec = _batch_spec(mesh, q.shape[0])
     hspec = P(bspec, None, "model", None)  # heads/kv-heads on axis 2
-    traced_window = window is not None and not isinstance(window, int)
 
     arrs = [q, k_buf, v_buf, jnp.asarray(q_offset, jnp.int32)]
     specs = [hspec, hspec, hspec, P()]
-    if traced_window:
-        arrs.append(jnp.asarray(window, jnp.int32))
-        specs.append(P())
     if n_total is not None:
         arrs.append(jnp.asarray(n_total, jnp.int32))
         specs.append(P())
 
     def local(qv, kv, vv, off, *rest):
-        win = rest[0] if traced_window else window
         if not score_masses:
             return (ops.chunk_attention(qv, kv, vv, q_offset=off,
-                                        window=win),)
+                                        window=window),)
         nt = rest[-1] if n_total is not None else None
-        return ops.chunk_attention(qv, kv, vv, q_offset=off, window=win,
+        return ops.chunk_attention(qv, kv, vv, q_offset=off, window=window,
                                    score_masses=True, n_total=nt)
 
     out_specs = (hspec, P(bspec, "model", None)) if score_masses else (hspec,)
@@ -444,16 +474,12 @@ def sharded_lookahead_score(q_obs, k_buf, n_prompt, *, q_offset, window,
 
     bspec = _batch_spec(smesh, q_obs.shape[0])
     hspec = P(bspec, None, "model", None)
-    traced_window = window is not None and not isinstance(window, int)
     traced_offset = q_offset is not None and not isinstance(q_offset, int)
 
     arrs = [q_obs, k_buf]
     specs = [hspec, hspec]
     if traced_offset:
         arrs.append(jnp.asarray(q_offset, jnp.int32))
-        specs.append(P())
-    if traced_window:
-        arrs.append(jnp.asarray(window, jnp.int32))
         specs.append(P())
     if row_valid is not None:
         arrs.append(row_valid)
@@ -465,13 +491,9 @@ def sharded_lookahead_score(q_obs, k_buf, n_prompt, *, q_offset, window,
         if traced_offset:
             off = rest[i]
             i += 1
-        win = window
-        if traced_window:
-            win = rest[i]
-            i += 1
         rv = rest[i] if row_valid is not None else None
         return ops.lookahead_score(qv, kv, n_prompt, q_offset=off,
-                                   window=win, row_valid=rv)
+                                   window=window, row_valid=rv)
 
     return _shard_map(local, smesh, tuple(specs),
                       P(bspec, "model", None))(*arrs)
@@ -527,6 +549,7 @@ def decode_attention_step(
     inp: AttnInputs,
     *,
     window=None,
+    is_global: jnp.ndarray | bool = True,
 ) -> tuple[jnp.ndarray, dict]:
     """One decode step against the cache.  Returns (out (B,1,D), new cache).
 
@@ -542,7 +565,7 @@ def decode_attention_step(
     cache = inp.cache
     B = h1.shape[0]
     KV = a.num_kv_heads
-    q, k_new, v_new = qkv(p, a, h1, inp)
+    q, k_new, v_new = qkv(p, a, h1, inp, is_global=is_global)
     cursor = inp.cache_cursor
     new_pos = jnp.broadcast_to(inp.positions[:, :, None], (B, 1, KV))
     if getattr(cursor, "ndim", 0) == 1:  # per-slot cursors
@@ -581,6 +604,7 @@ def decode_attention_step_paged(
     depth: int,  # static dense-equivalent cache depth (capacity + margin)
     active: Optional[jnp.ndarray] = None,  # (B,) live-slot mask
     window=None,
+    is_global: jnp.ndarray | bool = True,
 ) -> tuple[jnp.ndarray, dict]:
     """One decode step against the *paged* cache (``serving/kv_pool.py``).
 
@@ -631,7 +655,7 @@ def decode_attention_step_paged(
     bs = pool["k"].shape[2]
     nb = table.shape[1]
     assert depth <= nb * bs, "block table shallower than the logical cache"
-    q, k_new, v_new = qkv(p, a, h1, inp)
+    q, k_new, v_new = qkv(p, a, h1, inp, is_global=is_global)
     cursor = inp.cache_cursor  # (B,) per-slot append cursors
     new_pos = jnp.broadcast_to(inp.positions[:, :, None], (B, 1, KV))
 
@@ -741,6 +765,7 @@ def decode_attention_step_evicting(
     inp: AttnInputs,
     *,
     window=None,
+    is_global: jnp.ndarray | bool = True,
 ) -> tuple[jnp.ndarray, dict]:
     """Decoding-stage eviction step (beyond-paper: the paper names decode
     eviction as future work).  The cache carries a ``score`` field —
@@ -754,7 +779,7 @@ def decode_attention_step_evicting(
     KV, hd = a.num_kv_heads, a.head_dim
     C = cache["k"].shape[1]
     G = a.num_heads // KV
-    q, k_new, v_new = qkv(p, a, h1, inp)
+    q, k_new, v_new = qkv(p, a, h1, inp, is_global=is_global)
 
     # one-step attention distribution of the new query over current slots,
     # grouped by kv head: (B, KV, G, C)
@@ -837,6 +862,7 @@ def decode_attention_step_split(
     inp: AttnInputs,
     *,
     window=None,
+    is_global: jnp.ndarray | bool = True,
 ) -> tuple[jnp.ndarray, dict]:
     """Split-cache decode (§Perf decode iteration): the prompt cache is
     *frozen* (read-only — it may stay sequence-sharded on "model" with no
@@ -851,7 +877,7 @@ def decode_attention_step_split(
     B = h1.shape[0]
     KV = a.num_kv_heads
     Hb = cache["hot_k"].shape[1]
-    q, k_new, v_new = qkv(p, a, h1, inp)
+    q, k_new, v_new = qkv(p, a, h1, inp, is_global=is_global)
     cursor = inp.cache_cursor  # counts hot-buffer appends (ring)
     slot = jnp.mod(cursor, Hb)
     hot_k = jax.lax.dynamic_update_slice(cache["hot_k"], k_new,

@@ -16,9 +16,15 @@ softmax gating).  Two dispatch strategies:
   dropping): top-k FLOPs only, at the cost of gather/scatter + (under SPMD)
   dispatch collectives.  Used by the beyond-paper perf variant.
 
+Expert parallelism: a layer may hold a share of the experts
+(``MoEConfig.experts_held`` from ``first_expert``).  The router keeps all
+``num_experts`` outputs and its top-k; the layer adds only the gated
+outputs of those of a token's top-k that it holds — what one chip of an
+expert-parallel deployment computes before the exchange.
+
 Params:
     router: (D, E) f32
-    experts: {w_gate/w_up: (E, D, Fe), w_down: (E, Fe, D)}
+    experts: {w_gate/w_up: (E_held, D, Fe), w_down: (E_held, Fe, D)}
     shared:  {w_gate/w_up: (D, Sh*Fe), w_down: (Sh*Fe, D)}  (fused)
 """
 
@@ -41,14 +47,14 @@ def init(key, cfg: ModelConfig) -> dict:
     m = cfg.moe
     dtype = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 8)
-    E, D, Fe = m.num_experts, cfg.d_model, m.d_expert
+    E, D, Fe = m.held, cfg.d_model, m.d_expert
 
     def experts_init(k, d_in, d_out):
         keys = jax.random.split(k, E)
         return jax.vmap(lambda kk: dense_init(kk, d_in, d_out, dtype))(keys)
 
     p = {
-        "router": dense_init(ks[0], D, E, jnp.float32),  # router in f32
+        "router": dense_init(ks[0], D, m.num_experts, jnp.float32),  # f32
         "experts": {
             "w_gate": experts_init(ks[1], D, Fe),
             "w_up": experts_init(ks[2], D, Fe),
@@ -66,19 +72,33 @@ def init(key, cfg: ModelConfig) -> dict:
 
 
 def _route(p: dict, cfg: ModelConfig, h: jnp.ndarray):
-    """Router: returns (combine (B,S,E) f32, aux loss scalar, gates, idx)."""
+    """Router over all ``num_experts``: softmax, top-k, the k gates
+    renormalised.  Returns (combine (B,S,E_held) f32: each held expert's
+    gate, zero where the token did not pick it; aux loss scalar; gates;
+    idx (global expert ids))."""
     m = cfg.moe
     E, K = m.num_experts, m.top_k
-    logits = h.astype(jnp.float32) @ p["router"]  # (B,S,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)  # (B,S,K)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # (B,S,K,E)
-    combine = jnp.einsum("bske,bsk->bse", onehot, gate_vals)
-    frac_tokens = jnp.mean(onehot.sum(axis=2), axis=(0, 1))  # (E,)
-    frac_probs = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(frac_tokens * frac_probs) * m.load_balance_coef
+    with jax.named_scope("moe_router"):
+        logits = h.astype(jnp.float32) @ p["router"]  # (B,S,E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, K)  # (B,S,K)
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
+        onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # (B,S,K,E)
+        combine = jnp.einsum("bske,bsk->bse", onehot, gate_vals)
+        frac_tokens = jnp.mean(onehot.sum(axis=2), axis=(0, 1))  # (E,)
+        frac_probs = jnp.mean(probs, axis=(0, 1))
+        aux = E * jnp.sum(frac_tokens * frac_probs) * m.load_balance_coef
+        if m.held != E:
+            combine = combine[..., m.first_expert:m.first_expert + m.held]
     return combine, aux, gate_vals, gate_idx
+
+
+def routed_rows(combine: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    """(E_held,) int32: how many of the ``rows`` (a (B, S) bool mask) put
+    each held expert among their top-k."""
+    picked = (combine > 0) & rows[..., None]
+    return picked.sum(axis=(0, 1), dtype=jnp.int32)
 
 
 def _shared_out(p, cfg, h, *, lora=None, lora_mask=None, lora_scale=1.0):
@@ -102,10 +122,14 @@ def apply(
     lora: Optional[dict] = None,
     lora_mask: Optional[jnp.ndarray] = None,
     lora_scale: float = 1.0,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Dense-dispatch MoE.  Returns (out (B,S,D), aux_loss scalar)."""
+    count_rows: Optional[jnp.ndarray] = None,
+):
+    """Dense-dispatch MoE over the held experts.  Returns (out (B,S,D),
+    aux_loss scalar), and ``routed_rows`` of the ``count_rows`` mask
+    third where one is given."""
     B, S, D = h.shape
     combine, aux, _, _ = _route(p, cfg, h)
+    counts = None if count_rows is None else routed_rows(combine, count_rows)
     hx = h
     chunk = min(_CHUNK, S)
     pad = (-S) % chunk
@@ -128,13 +152,16 @@ def apply(
         eo = jnp.einsum("bsef,efd->bsed", y, ew["w_down"])
         return jnp.einsum("bsed,bse->bsd", eo.astype(jnp.float32), cc)
 
-    outs = jax.lax.map(one_chunk, (hx, cmb))  # (n, B, chunk, D) f32
+    with jax.named_scope("moe_experts"):
+        outs = jax.lax.map(one_chunk, (hx, cmb))  # (n, B, chunk, D) f32
     out = jnp.moveaxis(outs, 0, 1).reshape(B, nchunks * chunk, D)[:, :S]
     out = out.astype(h.dtype)
 
     if "shared" in p:
         out = out + _shared_out(p, cfg, h, lora=lora, lora_mask=lora_mask,
                                 lora_scale=lora_scale)
+    if counts is not None:
+        return out, aux, counts
     return out, aux
 
 
@@ -154,6 +181,8 @@ def apply_sparse(
     is zero; the residual stream and shared experts still flow).
     """
     m = cfg.moe
+    assert m.held == m.num_experts, \
+        "capacity dispatch serves a layer that holds every expert"
     B, S, D = h.shape
     E, K = m.num_experts, m.top_k
     N = B * S

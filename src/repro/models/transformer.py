@@ -426,8 +426,8 @@ def prefill(
                 ys["cross_cache"] = dict(ev.evict_layer(
                     sc, x["ck"], x["cv"], min(evict.cross_budget, Se)
                 )._asdict())
-        h, ys["aux"] = _ffn_residual(h, lp, cfg, lora_l=lora_l,
-                                     lora_mask=lookahead_mask, ls=ls)
+        h, ys["aux"], _ = _ffn_residual(h, lp, cfg, lora_l=lora_l,
+                                        lora_mask=lookahead_mask, ls=ls)
 
         # ---- scoring + eviction (attention archs only) ----
         if cfg.uses_attention and needs_scores and obs_policy is not None:
@@ -623,32 +623,42 @@ def resume_chunk_state(snap: ChunkState, capacity: int) -> ChunkState:
 
 
 def _ffn_residual(h, lp, cfg: ModelConfig, *, lora_l=None, lora_mask=None,
-                  ls: float = 1.0, smesh=None):
+                  ls: float = 1.0, smesh=None, count_rows=None):
     """The post-attention half of a block (MoE or MLP residual) — the one
     definition shared by monolithic prefill, the chunk step, the
     observation pass (which thread the lookahead LoRA), and decode.
-    Returns (h, aux) where aux is the MoE load-balance loss (zero
-    otherwise).  With ``smesh`` (tensor-sharded serving) every FFN dot
+    Returns (h, aux, routed) where aux is the MoE load-balance loss (zero
+    otherwise) and routed the MoE layer's ``moe.routed_rows`` of the
+    ``count_rows`` mask ((B, S) bool; None without one, or without MoE).
+    With ``smesh`` (tensor-sharded serving) every FFN dot
     must keep the single-device summation order — GSPMD's realization is
     shape-dependent, so the dense MLP runs manual column-parallel TP
     (``mlp.apply_sharded``) and MoE runs replicated under shard_map
     (``attention.replicated_apply`` — exact but redundant; sharded-exact
     MoE dispatch is out of scope)."""
     aux = jnp.zeros((), jnp.float32)
+    routed = None
     if cfg.moe is not None:
         u = rms_norm(h, lp["ln2"], cfg.norm_eps)
         moe_lora = None
         if lora_l is not None and lora_l.get("moe"):
             moe_lora = lora_l["moe"].get("shared")
-        apply = (moe_mod.apply_sparse if cfg.moe.dispatch == "sparse"
-                 else moe_mod.apply)
-        fn = lambda pp, uu, lo, lm: apply(pp, cfg, uu, lora=lo,
-                                          lora_mask=lm, lora_scale=ls)
+        if cfg.moe.dispatch == "sparse":
+            assert count_rows is None, "routed rows are counted densely"
+            fn = lambda pp, uu, lo, lm: moe_mod.apply_sparse(
+                pp, cfg, uu, lora=lo, lora_mask=lm, lora_scale=ls)
+        else:
+            fn = lambda pp, uu, lo, lm: moe_mod.apply(
+                pp, cfg, uu, lora=lo, lora_mask=lm, lora_scale=ls,
+                count_rows=count_rows)
         if smesh is not None:
-            mo, aux = attn_mod.replicated_apply(
+            res = attn_mod.replicated_apply(
                 fn, smesh, lp["moe"], u, moe_lora, lora_mask)
         else:
-            mo, aux = fn(lp["moe"], u, moe_lora, lora_mask)
+            res = fn(lp["moe"], u, moe_lora, lora_mask)
+        mo, aux = res[:2]
+        if count_rows is not None:
+            routed = res[2]
         h = h + mo
     elif cfg.d_ff > 0:
         u = rms_norm(h, lp["ln2"], cfg.norm_eps)
@@ -656,7 +666,7 @@ def _ffn_residual(h, lp, cfg: ModelConfig, *, lora_l=None, lora_mask=None,
         mo = mlp_mod.apply_sharded(lp["mlp"], cfg, u, smesh, lora=mlp_lora,
                                    lora_mask=lora_mask, lora_scale=ls)
         h = h + mo
-    return h, aux
+    return h, aux, routed
 
 
 def prefill_chunk(
@@ -668,7 +678,8 @@ def prefill_chunk(
     *,
     policy: str,
     mesh=None,  # serving mesh: per-shard head dispatch in the chunk kernel
-) -> tuple[ChunkState, jnp.ndarray]:
+    routed: Optional[jnp.ndarray] = None,  # (E_held,) int32 MoE tally
+):
     """Process one fixed-size prompt chunk starting at ``state.pos``.
 
     Returns (state', logits (B, V) of the chunk's last *real* row) — the
@@ -676,7 +687,9 @@ def prefill_chunk(
     distribution.  Pad rows in a partial final chunk are harmless: causal
     masking hides their keys from every real row, they carry zero score
     weight, and the finalize step masks their buffer columns out of the
-    cache.
+    cache.  With ``routed`` (an MoE model's running tally) a third output
+    adds the chunk's real rows routed to each held expert, summed over the
+    layers (``moe.routed_rows``).
     """
     a = cfg.attn
     assert chunkable(cfg), "chunked prefill serves attention-only archs"
@@ -699,6 +712,9 @@ def prefill_chunk(
     # cumulative policies take their per-chunk column-mass partials straight
     # from the attention kernel's fused second output — no dense score block
     want_masses = policy in scoring.STREAMING_CUMULATIVE
+    count_rows = None
+    if routed is not None:
+        count_rows = jnp.broadcast_to(s + jnp.arange(C) < n_total, (B, C))
 
     def body(h, x):
         lp = x["p"]
@@ -710,8 +726,11 @@ def prefill_chunk(
             is_global=flag, score_masses=want_masses, n_total=n_total,
         )
         h = h + out
-        h, _ = _ffn_residual(h, lp, cfg, smesh=smesh)
+        h, _, routed_l = _ffn_residual(h, lp, cfg, smesh=smesh,
+                                       count_rows=count_rows)
         ys: dict = {"k": k_buf, "v": v_buf}
+        if routed_l is not None:
+            ys["routed"] = routed_l
         acc_l, qbuf_l = scoring.update_layer_scores(
             policy, x.get("acc"), x.get("qbuf"), q, masses_l=masses,
             q_offset=s, n_total=n_total,
@@ -734,10 +753,10 @@ def prefill_chunk(
         score = score._replace(qbuf=ys["qbuf"])
     row = jnp.clip(n_total - 1 - s, 0, C - 1)
     logits = unembed(params, cfg, h[jnp.arange(B), row])
-    return (
-        ChunkState(k=ys["k"], v=ys["v"], score=score, pos=s + C),
-        logits,
-    )
+    state = ChunkState(k=ys["k"], v=ys["v"], score=score, pos=s + C)
+    if routed is not None:
+        return state, logits, routed + ys["routed"].sum(0)
+    return state, logits
 
 
 def _chunk_observation_pass(
@@ -799,15 +818,14 @@ def _chunk_observation_pass(
                 lora_scale=ls,
             )
             h = h + out
-            h, _ = _ffn_residual(h, lp, cfg, lora_l=lora_l, lora_mask=lmask,
-                                 ls=ls, smesh=smesh)
+            h, _, _ = _ffn_residual(h, lp, cfg, lora_l=lora_l,
+                                    lora_mask=lmask, ls=ls, smesh=smesh)
         # the masked streaming primitive scores the observation rows over
         # the whole buffer (mean over the n_obs rows, traced row base)
         with jax.named_scope("lookahead_scores"):
-            masses = attn_mod.sharded_lookahead_score(
-                q, k_buf, K, q_offset=n_total, window=layer_window(a, flag),
-                smesh=smesh,
-            )
+            masses = attn_mod.by_kind(a, flag, lambda w: (
+                attn_mod.sharded_lookahead_score(
+                    q, k_buf, K, q_offset=n_total, window=w, smesh=smesh)))
         return h, {"k": k_buf, "v": v_buf, "obs": masses}
 
     _, ys = jax.lax.scan(body, h, xs)
@@ -869,16 +887,20 @@ def prefill_finalize(
         flag = x.get("flag", True)
         with jax.named_scope("lookahead_scores"):
             if policy in OBS_POLICIES:
-                s_kv = scoring.finalize_layer_scores(
-                    policy, x["k"], n_total,
-                    acc_l=x.get("acc"), cnt=state.score.cnt,
-                    qbuf_l=x.get("qbuf"), obs_masses_l=x.get("obs"),
-                    num_kv_heads=a.num_kv_heads,
-                    pool_kernel=lk.pool_kernel if lk else 7,
-                    window_size=lk.window_size if lk else 32,
-                    window=layer_window(a, flag),
-                    smesh=smesh,
-                )
+                def score(w):
+                    return scoring.finalize_layer_scores(
+                        policy, x["k"], n_total,
+                        acc_l=x.get("acc"), cnt=state.score.cnt,
+                        qbuf_l=x.get("qbuf"), obs_masses_l=x.get("obs"),
+                        num_kv_heads=a.num_kv_heads,
+                        pool_kernel=lk.pool_kernel if lk else 7,
+                        window_size=lk.window_size if lk else 32,
+                        window=w, smesh=smesh)
+
+                # only the rolled-window policies score here under a window
+                s_kv = (attn_mod.by_kind(a, flag, score)
+                        if policy in scoring.STREAMING_WINDOW
+                        else score(layer_window(a, flag)))
             else:
                 s_kv = ev.position_scores(
                     policy, K, B, a.num_kv_heads, sink=evict.sink,
@@ -1187,7 +1209,7 @@ def decode_step(
                 win = layer_window(a, flag)
                 if paged:
                     a_out, new_c = attn_mod.decode_attention_step_paged(
-                        lp["attn"], a, u, inp, window=win,
+                        lp["attn"], a, u, inp, window=win, is_global=flag,
                         table=cache["attn"]["table"], depth=paged_depth,
                         active=active)
                 else:
@@ -1197,7 +1219,8 @@ def decode_step(
                         step_fn = attn_mod.decode_attention_step_evicting
                     else:
                         step_fn = attn_mod.decode_attention_step
-                    a_out, new_c = step_fn(lp["attn"], a, u, inp, window=win)
+                    a_out, new_c = step_fn(lp["attn"], a, u, inp, window=win,
+                                           is_global=flag)
                 delta = delta + a_out
                 ys["attn_cache"] = new_c
             if cfg.uses_ssm:
@@ -1215,7 +1238,7 @@ def decode_step(
             else:
                 h = h + attn_mod.cross_attention(lp["cross"], a, u,
                                                  x["ck"], x["cv"])
-        h, _ = _ffn_residual(h, lp, cfg, smesh=smesh)
+        h, _, _ = _ffn_residual(h, lp, cfg, smesh=smesh)
         return h, ys
 
     h, ys = jax.lax.scan(body, h, xs)

@@ -290,13 +290,14 @@ class _InflightPrefill:
     is the deepest pinned prefix-cache entry along this request's prompt
     (the resume point on a hit, then each freshly inserted boundary)."""
 
-    __slots__ = ("req", "state", "n", "s", "logits", "tip")
+    __slots__ = ("req", "state", "n", "s", "logits", "tip", "routed")
 
     def __init__(self, req: Request, state, n: int):
         self.req, self.state, self.n = req, state, n
         self.s = 0
         self.logits = None
         self.tip = None
+        self.routed = None  # MoE: device tally of this request's chunks
 
 
 class _SlotDecodeMixin:
@@ -447,6 +448,8 @@ class _LegacyStatsView(Mapping):
                     int(v("serving_decode_evict_sweeps_total"))
             if e._last_sched is not None:
                 d["kv_pool"] = e._last_sched.pool_stats()
+        if e.cfg.moe is not None:
+            d["moe.routed_rows"] = e.routed_rows()
         return d
 
     def __getitem__(self, key):
@@ -676,6 +679,10 @@ class ContinuousEngine(_SlotDecodeMixin):
                 assert prefix_cache.pool is kv_pool, \
                     "prefix cache bound to a different block pool"
         self.capture_admission = config.capture_admission
+        # MoE: each request's chunk programs tally the prompt rows routed
+        # to each held expert on the device; the tally is read once, when
+        # the request retires (its chunks finished long before)
+        self._routed_slot: dict[int, jnp.ndarray] = {}
         # -- observability (repro.obs) ----------------------------------
         # one typed registry per engine replaces the historical ad-hoc
         # ``stats`` dict (kept below as a deprecated read-only view);
@@ -759,6 +766,12 @@ class ContinuousEngine(_SlotDecodeMixin):
         self._m_sweeps = m.counter(
             "serving_decode_evict_sweeps_total",
             "Decode-time evict-and-compact sweeps on the paged pool.")
+        if self.cfg.moe is not None:
+            self._m_routed = m.counter(
+                "moe.routed_rows",
+                "Prompt rows of retired requests routed to each held "
+                "expert (one count per (row, layer, expert) among the "
+                "row's top-k).", labelnames=("expert",))
         self._m_build = m.info(
             "serving_build",
             "Engine build facts: score/decode dispatch path, device mesh, "
@@ -794,7 +807,12 @@ class ContinuousEngine(_SlotDecodeMixin):
 
     # -- compile-cache bodies ------------------------------------------------
     def _build(self, kind: str, policy: str):
-        if kind == "chunk":
+        if kind == "chunk" and self.cfg.moe is not None:
+            def fn(params, state, tokens, n_total, routed):
+                return tf.prefill_chunk(params, self.cfg, state, tokens,
+                                        n_total, policy=policy,
+                                        mesh=self.mesh, routed=routed)
+        elif kind == "chunk":
             def fn(params, state, tokens, n_total):
                 return tf.prefill_chunk(params, self.cfg, state, tokens,
                                         n_total, policy=policy,
@@ -910,10 +928,6 @@ class ContinuousEngine(_SlotDecodeMixin):
         active = np.zeros(self.num_slots, bool)
         remaining = np.zeros(self.num_slots, np.int64)
         last_emit = np.zeros(self.num_slots, np.float64)
-        # fused Pallas scoring requires a *static* per-layer window —
-        # patterned local:global archs trace the window inside the layer
-        # scan, which routes ops.chunk_attention to the jnp fallback
-        static_window = tf.is_global_flags(self.cfg) is None
         # fresh collection epoch per run (the historical per-run stats
         # semantics benches rely on: warm up, then time the same engine);
         # callback gauges mirror live component state and are untouched
@@ -923,8 +937,10 @@ class ContinuousEngine(_SlotDecodeMixin):
         self._uid_seq = {}
         sched.bind_metrics(self.metrics)
         self._run_info = {
-            "score_path": ("pallas-fused"
-                           if ops.use_pallas() and static_window
+            # a local:global pattern's kernel calls take each layer's
+            # window as a static value (attention.by_kind), so the
+            # kernels serve every layer
+            "score_path": ("pallas-fused" if ops.use_pallas()
                            else "jnp-fallback"),
             # which paged_decode_attention tier serves this run
             # (kernel / gather / fallback); "dense" when unpooled — whose
@@ -1163,8 +1179,14 @@ class ContinuousEngine(_SlotDecodeMixin):
             seg = pf.req.prompt[pf.s:pf.s + self.chunk]
             blk[0, :len(seg)] = seg
             fn = self.chunk_cache.get("chunk", self.chunk, 1, self.policy)
-            pf.state, pf.logits = fn(self.params, pf.state, jnp.asarray(blk),
-                                     jnp.asarray(pf.n, jnp.int32))
+            args = (self.params, pf.state, jnp.asarray(blk),
+                    jnp.asarray(pf.n, jnp.int32))
+            if self.cfg.moe is not None:
+                if pf.routed is None:
+                    pf.routed = jnp.zeros((self.cfg.moe.held,), jnp.int32)
+                pf.state, pf.logits, pf.routed = fn(*args, pf.routed)
+            else:
+                pf.state, pf.logits = fn(*args)
             if self._sync_timers:
                 # device-time attribution: without the block the span measures
                 # dispatch only (JAX async dispatch)
@@ -1222,6 +1244,8 @@ class ContinuousEngine(_SlotDecodeMixin):
                 live = self._insert_fn(live, cache, slot)
         now = time.perf_counter() - t0
         self._seeds_h[slot] = r.eviction_seed
+        if pf.routed is not None:
+            self._routed_slot[slot] = pf.routed
         with self._phase("first_token"):
             first = self._first_token(pf.logits, r.eviction_seed, pf.n)
             tok = tok.at[slot, 0].set(first)
@@ -1500,7 +1524,18 @@ class ContinuousEngine(_SlotDecodeMixin):
                     end["blocks_freed"] = len(freed)
             self._m_sweeps.inc()
 
+    def routed_rows(self) -> dict:
+        """``moe.routed_rows`` so far: {global expert id: rows}."""
+        m = self.cfg.moe
+        ids = range(m.first_expert, m.first_expert + m.held)
+        return {e: int(self._m_routed.value(expert=str(e))) for e in ids}
+
     def _on_retire(self, slot: int, req: Request) -> None:
+        routed = self._routed_slot.pop(slot, None)
+        if routed is not None:
+            for i, n in enumerate(np.asarray(routed).tolist()):
+                self._m_routed.inc(
+                    n, expert=str(self.cfg.moe.first_expert + i))
         if not (self.capture_admission and self.pool is not None):
             return
         fn = self._decode_fns.get("retire_gather")
@@ -1593,6 +1628,7 @@ class ContinuousEngine(_SlotDecodeMixin):
         r.preempt_emit_s = last_emit[slot]  # the stall starts here
         r.cached_prefix_tokens = 0
         r.admission_cache = None
+        self._routed_slot.pop(slot, None)  # the re-serve counts again
         self._free_slot_blocks(slot)
         active[slot] = False
         remaining[slot] = 0

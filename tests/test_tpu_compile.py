@@ -145,6 +145,9 @@ def _named_cases():
         "chunk_attention": (
             lambda q, k, v, off, nt: chunk_attention_pallas(
                 q, k, v, off, block_k=512), chunk),
+        "chunk_attention_window": (
+            lambda q, k, v, off, nt: chunk_attention_pallas(
+                q, k, v, off, window=512, block_k=512), chunk),
         "chunk_attention_masses": (
             lambda q, k, v, off, nt: chunk_attention_masses_pallas(
                 q, k, v, off, nt, block_k=512), chunk),
@@ -347,3 +350,37 @@ def test_model4_programs_move_no_weights(topology, monkeypatch, program):
         spec = stored[block][name]
         assert declared.get(leaf.shape[1:]) == {tuple(spec)[1:]}, (
             name, spec, declared.get(leaf.shape[1:]))
+
+
+def test_mellum2_period_runs_both_chunk_kernels(one_chip, monkeypatch):
+    """One period of Mellum2 (three sliding layers, one full) at published
+    widths, 8 of 64 experts held: the chunk program lowers every layer's
+    attention to the Pallas kernel, the windowed layers' under their own
+    name."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import transformer as tf
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_pallas_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("mellum2-12b-ep8"), num_layers=4)
+
+    def sds(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = sds(jax.eval_shape(lambda k: tf.init_params(k, cfg),
+                                jax.random.PRNGKey(0)))
+    state = sds(jax.eval_shape(
+        lambda: tf.init_chunk_state(cfg, "lookaheadkv", 1, 2048)))
+    i32 = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+           for s in ((1, 256), (), (cfg.moe.held,))]
+    text = jax.jit(lambda p, s, t, n, r: tf.prefill_chunk(
+        p, cfg, s, t, n, policy="lookaheadkv", routed=r)).lower(
+            params, state, *i32).compile().as_text()
+    calls = re.findall(
+        r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert {re.sub(r"\.\d+$", "", c) for c in calls} == {
+        "chunk_attention", "chunk_attention_window"}, calls
