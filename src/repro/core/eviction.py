@@ -130,12 +130,18 @@ def gather_kv(
     idx: jnp.ndarray,  # (B, KV, capacity)
     mask: jnp.ndarray,  # (B, KV, capacity)
 ) -> EvictedKV:
-    """Per-kv-head gather of the retained slots."""
-    B, S, KV, hd = k.shape
-    cap = idx.shape[-1]
-    ik = jnp.swapaxes(idx, 1, 2)[..., None]  # (B, cap, KV, 1)
-    kk = jnp.take_along_axis(k, jnp.broadcast_to(ik, (B, cap, KV, hd)), axis=1)
-    vv = jnp.take_along_axis(v, jnp.broadcast_to(ik, (B, cap, KV, hd)), axis=1)
+    """Per-kv-head gather of the retained slots.
+
+    Each kept slot moves as one ``head_dim``-wide row: the gather indexes
+    only the row axis of each (batch, kv head), so XLA emits one index per
+    row rather than one per element (``select_topk``'s indices are always
+    in range)."""
+
+    def rows(x, i):  # (S, hd), (cap,) -> (cap, hd)
+        return jnp.take(x, i, axis=0, mode="clip")
+
+    take = jax.vmap(jax.vmap(rows, in_axes=(1, 0), out_axes=1))
+    kk, vv = take(k, idx), take(v, idx)
     pos = jnp.swapaxes(idx, 1, 2)  # (B, cap, KV)
     m = jnp.swapaxes(mask, 1, 2)
     kk = jnp.where(m[..., None], kk, 0)

@@ -17,3 +17,14 @@ def sweep_cases(seed: int, n: int, gen):
 
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running integration test")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _drop_compiled_programs():
+    """Free each module's compiled programs when it ends: a profile keeps
+    the HLO of every live program in the process, so a later module that
+    reads its own program from a profile would otherwise see this one's."""
+    yield
+    import jax
+
+    jax.clear_caches()

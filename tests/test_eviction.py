@@ -145,6 +145,53 @@ def test_gather_kv_zeroes_invalid():
     assert (masked == 0).all()
 
 
+def _element_gather(k, v, idx, mask):
+    """The element-indexed gather ``gather_kv`` replaced: the row index
+    broadcast across ``head_dim``, one index per scalar."""
+    B, S, KV, hd = k.shape
+    cap = idx.shape[-1]
+    ik = jnp.broadcast_to(jnp.swapaxes(idx, 1, 2)[..., None], (B, cap, KV, hd))
+    m = jnp.swapaxes(mask, 1, 2)
+    kk = jnp.where(m[..., None], jnp.take_along_axis(k, ik, axis=1), 0)
+    vv = jnp.where(m[..., None], jnp.take_along_axis(v, ik, axis=1), 0)
+    return ev.EvictedKV(k=kk, v=vv, pos=jnp.swapaxes(idx, 1, 2), mask=m)
+
+
+@pytest.mark.parametrize("variant", ["plain", "padded", "layer_budget",
+                                     "head_budgets", "key_mask",
+                                     "extra_slots"])
+@pytest.mark.parametrize("KV", [1, 2, 4])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_row_gather_matches_element_gather(monkeypatch, dtype, KV, variant):
+    """``evict_layer``'s row gather is bit for bit the element gather:
+    the same kept rows, zeroed invalid slots, positions and masks."""
+    B, n, hd, cap = 2, 48, 16, 20
+    ks = jax.random.split(jax.random.PRNGKey(KV), 4)
+    k = jax.random.normal(ks[0], (B, n, KV, hd)).astype(dtype)
+    v = jax.random.normal(ks[1], (B, n, KV, hd)).astype(dtype)
+    scores = jax.random.uniform(ks[2], (B, KV, n))
+    kw: dict = {}
+    if variant == "padded":
+        cap = n + 8  # capacity above the prompt: padded slots
+    elif variant == "layer_budget":
+        kw["layer_budget"] = jnp.asarray(13)
+    elif variant == "head_budgets":
+        kw["head_budgets"] = jax.random.randint(ks[3], (B, KV), 4, cap + 1)
+    elif variant == "key_mask":
+        cap = n  # selects padded keys, which come out masked
+        kw["key_mask"] = jnp.arange(n)[None, :] < jnp.asarray([[n - 9], [31]])
+    elif variant == "extra_slots":
+        kw["extra_slots"] = 7
+    got = ev.evict_layer(scores, k, v, cap, **kw)
+    monkeypatch.setattr(ev, "gather_kv", _element_gather)
+    want = ev.evict_layer(scores, k, v, cap, **kw)
+    assert int((~got.mask).sum()) > 0 or variant in ("plain", "head_budgets")
+    for name, a, b in zip(got._fields, got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert a.tobytes() == b.tobytes(), name
+
+
 def test_adaptive_head_budgets_pool_invariant():
     """Ada-KV allocation: per-head budgets vary with score concentration but
     the global pool KV·budget is preserved (±KV from int rounding)."""

@@ -352,6 +352,81 @@ def test_model4_programs_move_no_weights(topology, monkeypatch, program):
             name, spec, declared.get(leaf.shape[1:]))
 
 
+def _one_chip_finalize(sharding):
+    """The finalize program at qwen2-1.5b widths (two layers), keeping
+    1,024 rows per kv head of a 2,048-row buffer."""
+    import dataclasses
+
+    from repro.common.config import EvictionConfig
+    from repro.configs import get_config
+    from repro.core.lookahead import init_lookahead_params
+    from repro.models import transformer as tf
+
+    cfg = dataclasses.replace(get_config("qwen2-1.5b"), num_layers=2)
+
+    def init():
+        return tf.init_params(jax.random.PRNGKey(0), cfg)
+
+    def sds(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+
+    lkv = sds(jax.eval_shape(lambda: init_lookahead_params(
+        jax.random.PRNGKey(1), cfg, init()["layers"])))
+    state = sds(jax.eval_shape(
+        lambda: tf.init_chunk_state(cfg, "lookaheadkv", 1, 2048)))
+    i32 = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)
+           for s in ((), (1,))]
+
+    def finalize_fn(params, lkv, state, n_total, seeds):
+        return tf.prefill_finalize(
+            params, cfg, state, n_total, policy="lookaheadkv",
+            evict=EvictionConfig(budget=1024), lkv_params=lkv,
+            extra_slots=64, seeds=seeds)
+
+    return finalize_fn, (sds(jax.eval_shape(init)), lkv, state, *i32)
+
+
+@pytest.mark.parametrize("setting", ["one_chip", "model4"])
+def test_finalize_gathers_kv_rows(topology, monkeypatch, setting):
+    """``topk_evict`` moves each kept K/V row as one ``head_dim``-wide
+    slice: every gather of bf16 data in the optimized finalize program
+    takes ``head_dim``-wide slices, none emits the flat cap·KV·hd array
+    of an element-indexed gather, and under model=4 the eviction stays
+    split on kv heads with no collective."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_pallas_interpret", lambda: False)
+    if setting == "one_chip":
+        fn, args = _one_chip_finalize(SingleDeviceSharding(topology.devices[0]))
+        cap, kv = 1024, KV
+    else:
+        mesh = Mesh(np.asarray(topology.devices).reshape(1, 4),
+                    ("data", "model"))
+        _, _, programs = _tp_programs(mesh)
+        fn, args = programs["finalize"]
+        cap, kv = 128, 1  # budget 128; 4 kv heads split over 4 chips
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    gathers = re.findall(r"= bf16\[[^\]]*\]\S* gather\([^\n]*", text)
+    assert gathers and all(
+        re.search(rf"slice_sizes=\{{[\d,]*,{HD}\}}", g) for g in gathers), \
+        gathers
+    # each chip gathers the kept rows of its own kv heads only
+    evict = [re.match(r"= bf16\[([\d,]*)\]", g).group(1)
+             for g in gathers if "topk_evict" in g]
+    assert evict and all(np.prod([int(d) for d in dims.split(",")])
+                         == cap * kv * HD for dims in evict), evict
+    assert not re.search(rf"= bf16\[{cap * kv * HD}\]", text)
+    collectives = re.findall(
+        r"= \S+ (?:all-gather|all-reduce|all-to-all|collective-permute|"
+        r"reduce-scatter)(?:-start)?\([^\n]*", text)
+    assert not [c for c in collectives if "topk_evict" in c], collectives
+
+
 def test_mellum2_period_runs_both_chunk_kernels(one_chip, monkeypatch):
     """One period of Mellum2 (three sliding layers, one full) at published
     widths, 8 of 64 experts held: the chunk program lowers every layer's
